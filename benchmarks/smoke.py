@@ -222,11 +222,11 @@ def bench_sharded_frontend(n_orders: int = 1000, repeat: int = 5):
     smoke/normal plus an FKJoin, timed against the baseline so shard_map
     pipeline overhead regressions are caught per-PR even though the
     parent process only sees one device."""
-    from repro.compat import make_mesh
     from repro.db.plans import FKJoin
 
     db = tpch.generate(n_orders=n_orders, seed=0)
-    mesh = make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     li = Select(Scan("lineitem"), lambda t: t["l_shipdate"] > tpch.DAY0_1995)
     j = FKJoin(li, Scan("orders"), "l_orderkey", "o_orderkey",
                ("o_totalprice",))
@@ -289,11 +289,11 @@ def bench_shuffle_join(n_orders: int = 1000, repeat: int = 5):
     compiled once per strategy (a tiny per-join gather_budget forces the
     shuffle lowering), so the shuffle path's exchange overhead is gated
     per-PR alongside its memory accounting."""
-    from repro.compat import make_mesh
     from repro.db.plans import FKJoin
 
     db = tpch.generate(n_orders=n_orders, seed=0)
-    mesh = make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     li = Select(Scan("lineitem"), lambda t: t["l_shipdate"] > tpch.DAY0_1995)
     rows = []
     for tag, budget in (("gather", None), ("shuffle", 1)):
@@ -320,12 +320,12 @@ def bench_copartitioned_agg(n_orders: int = 1000, repeat: int = 5):
     Alongside the wall times, counts the shuffle_back round-trips each
     strategy traces — the fused pipeline must save at least one, and
     ``--check`` gates both the saving and fused-beats-shuffle."""
-    from repro.compat import make_mesh
     from repro.db import distributed as dist
     from repro.db.plans import FKJoin
 
     db = tpch.generate(n_orders=n_orders, seed=0)
-    mesh = make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     li = Select(Scan("lineitem"), lambda t: t["l_shipdate"] > tpch.DAY0_1995)
     j = FKJoin(li, Scan("orders"), "l_orderkey", "o_orderkey",
                ("o_totalprice",), gather_budget=1)

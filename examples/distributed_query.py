@@ -26,8 +26,8 @@ from repro.db import distributed as dist
 def main():
     n_dev = len(jax.devices())
     data = max(1, n_dev // 2)
-    from repro.compat import make_mesh
-    mesh = make_mesh((data, n_dev // data), ("data", "model"))
+    mesh = jax.make_mesh((data, n_dev // data), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     print(f"mesh: {dict(mesh.shape)} over {n_dev} host devices")
 
     n, G, F = 1 << 18, 256, 1024

@@ -11,8 +11,15 @@ where the distributed query step runs with the f32 log-CF kernel).
 """
 from __future__ import annotations
 
+import os
+
 import jax
 import jax.numpy as jnp
+
+#: The checkout root (``src/repro/core/config.py`` is three levels down).
+CHECKOUT_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
+
 
 def enable_x64() -> None:
     """Enable 64-bit mode. Call at entry points that need CPU f64 precision."""
@@ -29,3 +36,19 @@ def default_complex():
 
 def default_int():
     return jnp.int64 if x64_enabled() else jnp.int32
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is overridden.  Otherwise the cache goes to ``.jax_cache/`` at
+    the checkout root: a fixed path, because the path is part of what a
+    later process must find again (never a temporary name, pid or time).
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.join(CHECKOUT_ROOT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

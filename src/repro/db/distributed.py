@@ -72,9 +72,9 @@ from typing import Callable, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..compat import shard_map
 from ..core import uda
 from ..testing import faults
 from . import operators as ops

@@ -84,9 +84,9 @@ from typing import Callable, Dict, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..compat import shard_map
 from ..core import uda
 from ..testing import faults
 from . import cost as C
@@ -280,6 +280,10 @@ class LRUCache:
 
     def __contains__(self, key) -> bool:
         return key in self._data
+
+    def items(self) -> list:
+        """(key, value) pairs, least recently used first; counts nothing."""
+        return list(self._data.items())
 
     def get(self, key, default=None):
         if key in self._data:

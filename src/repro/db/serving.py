@@ -146,6 +146,13 @@ class PlanCache:
         the converged ``final_params`` hits the final attempt's entry."""
         return self.entry(root, mesh, jit=jit, **opts)[0].call
 
+    def entries(self, root: Node) -> list:
+        """Every cached entry compiled for ``root``, whatever the mesh or
+        lowering options: what a caller inspects to see which programs a
+        plan runs (``entry.call``, ``entry.batched``)."""
+        pk = plan_key(root)
+        return [e for k, e in self._lru.items() if k[1] == pk]
+
     @property
     def hits(self) -> int:
         return self._lru.hits

@@ -251,13 +251,28 @@ def q20_plan(nation_name: int = 3, max_groups: int = 1024,
                    ("s_name",), 64)
 
 
-def serving_plans(max_groups: int = 512) -> dict:
+def serving_plans(q3_groups: int = 512, q18_groups: int = 2048) -> dict:
     """One representative logical plan per TPC-H query — the serving
     workload (`launch/serve.py --db`) and the cache-hit bit-equality
-    tests submit exactly these."""
-    return {"q1": q1_plan(), "q3": q3_plan(max_groups=max_groups),
-            "q6": q6_plan(), "q18": q18_plan(max_groups=4 * max_groups),
+    tests submit exactly these.  The group-table sizes of the two
+    per-order plans default to small-scale values; size them from the
+    data with :func:`serving_group_sizes` (a table too small overflows)."""
+    return {"q1": q1_plan(), "q3": q3_plan(max_groups=q3_groups),
+            "q6": q6_plan(), "q18": q18_plan(max_groups=q18_groups),
             "q20": q20_plan()}
+
+
+def serving_group_sizes(db: TPCH) -> dict:
+    """``serving_plans`` keywords counted from the data: the distinct
+    order keys that survive Q3's join, and the distinct order keys of
+    lineitem (Q18's groups)."""
+    def distinct(t: Table, key: str) -> int:
+        keys = np.asarray(t[key])[np.asarray(t.valid)]
+        return max(1, np.unique(keys).size)
+
+    return dict(q3_groups=distinct(compile_plan(_q3_join())(db.tables()),
+                                   "l_orderkey"),
+                q18_groups=distinct(db.lineitem, "l_orderkey"))
 
 
 # ------------------------------------------------- parameterized families

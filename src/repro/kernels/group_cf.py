@@ -61,6 +61,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+# The segment-mask scatter must not round its f32 operand: Mosaic's
+# default matmul precision feeds the MXU bf16 (2^-9 relative per term).
+_EXACT = jax.lax.Precision.HIGHEST
+
 from . import pb_cf
 
 
@@ -79,7 +83,7 @@ def _group_logcf_kernel(gmin_ref, gmax_ref, flo_ref, p_ref, a_ref, a2_ref,
     # Skip the whole tile when this tuple block (sorted by gid) cannot touch
     # any of this block's group rows [gi*gb, gi*gb + gb).
     row_lo = gi * gb
-    hit = (gmin_ref[0, 0] < row_lo + gb) & (gmax_ref[0, 0] >= row_lo)
+    hit = (gmin_ref[ti] < row_lo + gb) & (gmax_ref[ti] >= row_lo)
 
     @pl.when(hit)
     def _acc():
@@ -87,7 +91,7 @@ def _group_logcf_kernel(gmin_ref, gmax_ref, flo_ref, p_ref, a_ref, a2_ref,
         # Global frequency index for every lane of this tile: (FB, 1).
         # freq_lo rides in as a run-time scalar (SMEM) so a traced
         # model-axis window parameterises ONE compiled kernel.
-        k = flo_ref[0, 0] + fi * fb \
+        k = flo_ref[0] + fi * fb \
             + jax.lax.broadcasted_iota(jnp.int32, (fb, 1), 0)
         k = jnp.minimum(k, n - 1)       # freq padding: extra lanes discarded
         k_hi = k >> shift
@@ -107,7 +111,7 @@ def _group_logcf_kernel(gmin_ref, gmax_ref, flo_ref, p_ref, a_ref, a2_ref,
         tiny = jnp.asarray(1e-30 if p.dtype == jnp.float32 else 1e-300,
                            p.dtype)
         la = 0.5 * jnp.log(jnp.maximum(re * re + im * im, tiny))
-        an = jnp.arctan2(im, re)
+        an = pb_cf.atan2(im, re)
 
         # Segment-mask scatter: rows (GB, 1) vs gids (1, TB) -> (GB, TB)
         # 0/1 mask; one MXU matmul contracts the tuple axis into (GB, FB).
@@ -115,9 +119,11 @@ def _group_logcf_kernel(gmin_ref, gmax_ref, flo_ref, p_ref, a_ref, a2_ref,
         m = (g_ref[...] == rows).astype(p.dtype)
         dims = (((1,), (1,)), ((), ()))
         la_ref[...] += jax.lax.dot_general(m, la, dims,
-                                           preferred_element_type=p.dtype)
+                                           preferred_element_type=p.dtype,
+                                           precision=_EXACT)
         an_ref[...] += jax.lax.dot_general(m, an, dims,
-                                           preferred_element_type=p.dtype)
+                                           preferred_element_type=p.dtype,
+                                           precision=_EXACT)
 
 
 def presort_operands(probs: jnp.ndarray, values: jnp.ndarray,
@@ -166,7 +172,7 @@ def group_logcf(probs: jnp.ndarray, values: jnp.ndarray, gids: jnp.ndarray,
     elif freq_cnt is None:
         raise ValueError("traced freq_lo requires an explicit freq_cnt")
     return _group_logcf(probs, values, gids,
-                        jnp.asarray(freq_lo, jnp.int32).reshape(1, 1),
+                        jnp.asarray(freq_lo, jnp.int32).reshape(1),
                         num_groups=num_groups, num_freq=num_freq,
                         freq_cnt=int(freq_cnt), gb=gb, fb=fb, tb=tb,
                         interpret=interpret, operands=operands)
@@ -200,22 +206,23 @@ def _group_logcf(probs, values, gids, freq_lo, *, num_groups: int,
     a2 = jnp.pad(a2, (0, ntp - nt))
 
     gblocks = g.reshape(-1, tb)
-    gmin = gblocks.min(axis=1).reshape(1, -1)      # (1, T_blocks) for SMEM
-    gmax = gblocks.max(axis=1).reshape(1, -1)
+    gmin = gblocks.min(axis=1)                     # (T_blocks,) in SMEM
+    gmax = gblocks.max(axis=1)
 
     ngp = pl.cdiv(num_groups, gb) * gb
     nfp = pl.cdiv(f, fb) * fb
     grid = (ngp // gb, nfp // fb, ntp // tb)
 
-    smem = dict(memory_space=pltpu.SMEM) if not interpret else {}
+    # The block ranges and freq_lo sit whole in SMEM (a (1, 1) block per
+    # step breaks the TPU's (8, 128)-or-full-dim block rule): 4 bytes per
+    # tuple block each, ~47 KB apiece at 6M tuples and tb=512.
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     la, an = pl.pallas_call(
         functools.partial(_group_logcf_kernel, num_freq=n,
                           shift=shift, gb=gb, fb=fb, tb=tb),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1), lambda g, f, t: (0, t), **smem),
-            pl.BlockSpec((1, 1), lambda g, f, t: (0, t), **smem),
-            pl.BlockSpec((1, 1), lambda g, f, t: (0, 0), **smem),
+            smem, smem, smem,
             pl.BlockSpec((1, tb), lambda g, f, t: (0, t)),
             pl.BlockSpec((1, tb), lambda g, f, t: (0, t)),
             pl.BlockSpec((1, tb), lambda g, f, t: (0, t)),

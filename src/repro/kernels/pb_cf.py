@@ -68,10 +68,37 @@ def _logcf_kernel(p_ref, a_ref, a2_ref, la_ref, an_ref, *,
     im = p * jnp.sin(theta)
     tiny = jnp.asarray(1e-30 if p.dtype == jnp.float32 else 1e-300, p.dtype)
     la = 0.5 * jnp.log(jnp.maximum(re * re + im * im, tiny))
-    an = jnp.arctan2(im, re)
+    an = atan2(im, re)
 
     la_ref[...] += la.sum(axis=1)[None, :]
     an_ref[...] += an.sum(axis=1)[None, :]
+
+
+# tan(pi/8): atan2's second range-reduction threshold.
+_TAN_PI_8 = 0.41421356237309503
+
+
+def atan2(y, x):
+    """``arctan2(y, x)`` from operations the TPU kernel lowering accepts
+    (Mosaic has no atan2 or atan): reduce to t = min(|x|,|y|)/max(|x|,|y|)
+    in [0, 1], fold t > tan(pi/8) onto z = (t-1)/(t+1) (+pi/4), evaluate
+    the Cephes ``atanf`` minimax odd polynomial on |z| <= tan(pi/8)
+    (relative error ~2e-7, one f32 ulp), then restore the octant and
+    quadrant.  ``re = 1-p + p cos(theta)`` is negative for p > 1/2 near
+    theta = pi, so all four quadrants occur.  The oracle
+    (:func:`repro.kernels.ref.logcf_ref`) keeps ``jnp.arctan2``."""
+    ax, ay = jnp.abs(x), jnp.abs(y)
+    hi = jnp.maximum(ax, ay)
+    t = jnp.minimum(ax, ay) / jnp.where(hi > 0, hi, 1)
+    fold = t > _TAN_PI_8
+    z = jnp.where(fold, (t - 1) / (t + 1), t)
+    z2 = z * z
+    r = ((((8.05374449538e-2 * z2 - 1.38776856032e-1) * z2
+           + 1.99777106478e-1) * z2 - 3.33329491539e-1) * z2 * z + z)
+    r = jnp.where(fold, r + math.pi / 4, r)
+    r = jnp.where(ay > ax, math.pi / 2 - r, r)
+    r = jnp.where(x < 0, math.pi - r, r)
+    return jnp.where(y < 0, -r, r)
 
 
 def phase_shift(num_freq: int) -> int:

@@ -7,13 +7,12 @@ from __future__ import annotations
 
 import jax
 
-from ..compat import make_mesh
-
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int | None = None, model: int | None = None):
@@ -24,4 +23,5 @@ def make_host_mesh(data: int | None = None, model: int | None = None):
         while model * 2 <= min(4, n) and n % (model * 2) == 0:
             model *= 2
         data = n // model
-    return make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs import base as cfgs
+from ..core.config import use_compile_cache
 from ..models import api
 
 
@@ -45,44 +46,62 @@ def generate(cfg, params, prompt, max_len: int, gen: int, greedy=True):
     return jnp.concatenate(out, axis=1)
 
 
-def serve_db(args) -> int:
-    """The ``--db`` service loop: submit / cached-hit / evict over the
-    TPC-H plan library, then the batched parameterized Q6 sweep."""
-    from ..db import tpch
-    from ..db.serving import QueryService
-
-    db = tpch.generate(n_orders=args.scale)
-    svc = QueryService(db.tables(), capacity=args.cache_capacity)
-    plans = tpch.serving_plans()
-    hit_seconds = 0.0
-    hit_requests = 0
-    for r in range(max(1, args.rounds)):
-        t0 = time.time()
-        hits = 0
+def serve_rounds(svc, plans: dict, rounds: int) -> list:
+    """Submit every plan once per round, each result waited for.  Returns
+    one ``(outs, infos, seconds)`` per round, keyed by plan name."""
+    history = []
+    for _ in range(rounds):
+        t0 = time.perf_counter()
+        outs, infos = {}, {}
         for name, plan in plans.items():
             out, info = svc.submit(plan)
             jax.block_until_ready(jax.tree.leaves(out))
-            hits += int(info["hit"])
-        dt = time.time() - t0
-        if r > 0:                     # warm rounds measure serving QPS
-            hit_seconds += dt
-            hit_requests += len(plans)
+            outs[name], infos[name] = out, info
+        history.append((outs, infos, time.perf_counter() - t0))
+    return history
+
+
+def sweep_points(n: int) -> dict:
+    """The Q6 what-if sweep's parameter batch: discount 5..7, quantity
+    limit 1..n."""
+    return dict(disc_lo=jnp.full((n,), 5), disc_hi=jnp.full((n,), 7),
+                qty_lim=jnp.arange(1, n + 1))
+
+
+def serve_db(args) -> int:
+    """The ``--db`` service loop: submit / cached-hit / evict over the
+    TPC-H plan library, then the batched parameterized Q6 sweep.  Every
+    submit runs under a retry policy, so an overflowing group table is
+    healed or reported instead of answered truncated."""
+    from ..db import tpch
+    from ..db.plans import RetryPolicy
+    from ..db.serving import QueryService
+
+    db = tpch.generate(n_orders=args.scale)
+    svc = QueryService(db.tables(), capacity=args.cache_capacity,
+                       policy=RetryPolicy())
+    plans = tpch.serving_plans(**tpch.serving_group_sizes(db))
+    history = serve_rounds(svc, plans, max(1, args.rounds))
+    for r, (_, infos, dt) in enumerate(history):
+        hits = sum(int(info["hit"]) for info in infos.values())
         print(f"[serve-db] round {r}: {len(plans)} queries in {dt:.3f}s "
               f"({hits}/{len(plans)} cache hits)")
-    if hit_requests:
+        bad = {name: info["report"].describe()
+               for name, info in infos.items() if not info["report"].ok()}
+        if bad:
+            print(f"[serve-db] round {r} unresolved: {bad}")
+            return 1
+    if len(history) > 1:              # warm rounds measure serving QPS
+        warm = sum(dt for _, _, dt in history[1:])
         print(f"[serve-db] cached throughput: "
-              f"{hit_requests / hit_seconds:.1f} queries/s")
+              f"{len(plans) * (len(history) - 1) / warm:.1f} queries/s")
     if args.sweep > 0:
-        n = args.sweep
-        batch = dict(disc_lo=jnp.full((n,), 5),
-                     disc_hi=jnp.full((n,), 7),
-                     qty_lim=jnp.arange(1, n + 1))
-        t0 = time.time()
-        out, info = svc.sweep(tpch.q6_family(), batch)
+        t0 = time.perf_counter()
+        out, info = svc.sweep(tpch.q6_family(), sweep_points(args.sweep))
         jax.block_until_ready(jax.tree.leaves(out))
-        print(f"[serve-db] batched q6 sweep: {n} points as "
+        print(f"[serve-db] batched q6 sweep: {args.sweep} points as "
               f"{info['launches']} device program(s) in "
-              f"{time.time() - t0:.3f}s")
+              f"{time.perf_counter() - t0:.3f}s")
     print(f"[serve-db] stats: {svc.stats.as_dict()}")
     print(f"[serve-db] plan cache: {svc.cache.info()}")
     return 0
@@ -107,6 +126,7 @@ def main(argv=None):
     ap.add_argument("--cache-capacity", type=int, default=16,
                     help="--db: bounded plan-cache entries")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     if args.db:
         return serve_db(args)

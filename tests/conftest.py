@@ -30,11 +30,11 @@ def run_sub(script: str, devices: int = 2) -> str:
 
 @pytest.fixture(autouse=True, scope="module")
 def _flat_compiler_footprint():
-    """The CPU backend here (jaxlib 0.4.36) segfaults inside
-    backend_compile once a single process accretes a few hundred live
-    compiled executables — the unmodified full suite dies with a fatal
-    SIGSEGV in whichever test file crosses the threshold (reproduced in
-    test_models and test_group_cf, always under compile_or_get_cached).
+    """The CPU backend segfaulted inside backend_compile once a single
+    process accreted a few hundred live compiled executables (seen with
+    jaxlib 0.4.36: the unmodified full suite died with a fatal SIGSEGV in
+    whichever test file crossed the threshold, always under
+    compile_or_get_cached).
     Dropping the jit caches at module boundaries keeps the compiler
     footprint flat; cross-module cache reuse is negligible since each
     file compiles its own shapes."""
@@ -55,14 +55,14 @@ def rng():
 # asserts exact equality — shapes, dtypes and every bit of every leaf.
 _MESH_EQUIV_TEMPLATE = '''
 import jax, jax.numpy as jnp, numpy as np
-from repro.compat import make_mesh
 from repro.core import enable_x64
 enable_x64()
 from repro.db import tpch
 from repro.db.plans import (FKJoin, GroupAgg, Map, Project, ReweightGreater,
                             Scan, Select, compile_plan)
 from repro.db.table import Table
-mesh = make_mesh((__DEVICES__,), ("data",))
+mesh = jax.make_mesh((__DEVICES__,), ("data",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 
 __SETUP__
 

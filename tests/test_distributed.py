@@ -15,8 +15,8 @@ def test_distributed_query_step_matches_reference():
 import numpy as np, jax, jax.numpy as jnp
 from repro.db import distributed as dist
 from repro.core import poisson_binomial as pb
-from repro.compat import make_mesh
-mesh = make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 n, G, F = 4096, 64, 512
 rng = np.random.default_rng(0)
 p = rng.uniform(0.01, 0.99, n).astype(np.float32)
@@ -40,11 +40,10 @@ print("OK")
 def test_compressed_psum_under_shard_map():
     out = run_sub("""
 import numpy as np, jax, jax.numpy as jnp
-from repro.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.train.optimizer import compressed_psum
-from repro.compat import make_mesh
-mesh = make_mesh((8,), ("pod",))
+mesh = jax.make_mesh((8,), ("pod",), axis_types=(jax.sharding.AxisType.Auto,))
 g = jnp.asarray(np.random.default_rng(0).normal(0, 1, (8, 256)), jnp.float32)
 err = jnp.zeros_like(g)
 def f(gs, es):
@@ -89,8 +88,8 @@ from repro.sharding import Rules
 from repro.train.optimizer import AdamW
 from repro.train.trainer import make_train_step
 cfg = get_reduced("yi_6b")
-from repro.compat import make_mesh
-mesh = make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 rules = Rules(mesh, fsdp=True)
 opt = AdamW(lr=1e-2, warmup=1)
 params = api.init_params(cfg, jax.random.PRNGKey(0))

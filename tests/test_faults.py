@@ -222,13 +222,12 @@ def test_wave_halving_retry():
 # ==================================== multi-device overflow + silent NaN
 _OVERFLOW_SCRIPT = r"""
 import jax, numpy as np, jax.numpy as jnp
-from repro.compat import make_mesh
 from repro.core import enable_x64
 enable_x64()
 from repro.db import plans as L
 from repro.db.table import Table
 
-mesh = make_mesh((3,), ("data",))
+mesh = jax.make_mesh((3,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
 n = 48
 # probe keys == 1 (mod 3): every row routes to one owner, so slack 0.25
 # buckets overflow under jit (traced keys keep the slack sizing).
@@ -308,13 +307,12 @@ def test_overflow_retry_3shard(copart):
 
 _FUZZ_SCRIPT = r"""
 import jax, numpy as np, jax.numpy as jnp
-from repro.compat import make_mesh
 from repro.core import enable_x64
 enable_x64()
 from repro.db import plans as L
 from repro.db.table import Table
 
-mesh = make_mesh((3,), ("data",))
+mesh = jax.make_mesh((3,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
 
 def trial(seed, copart):
     rng = np.random.default_rng(seed)

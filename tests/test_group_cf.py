@@ -318,12 +318,11 @@ def test_groupagg_exact_mesh_equivalence():
     and with a slab budget small enough to force multi-pass psum merges."""
     out = run_sub("""
 import jax, jax.numpy as jnp
-from repro.compat import make_mesh
 from repro.core import enable_x64
 enable_x64()
 from repro.db import tpch
 from repro.db.plans import GroupAgg, Scan, compile_plan
-mesh = make_mesh((2,), ("data",))
+mesh = jax.make_mesh((2,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
 db = tpch.generate(n_orders=64, seed=5)
 tables = db.tables()
 plan = GroupAgg(Scan("lineitem"), ("l_orderkey",), "l_quantity", "SUM",
@@ -350,7 +349,6 @@ def test_groupagg_exact_model_sharded_bit_equal():
     and under a slab budget that forces multi-pass accumulation."""
     out = run_sub("""
 import jax, jax.numpy as jnp, numpy as np
-from repro.compat import make_mesh
 from repro.core import enable_x64
 enable_x64()
 from repro.db import tpch
@@ -361,10 +359,13 @@ plan = GroupAgg(Scan("lineitem"), ("l_orderkey",), "l_quantity", "SUM",
                 128, "exact", num_freq=256,
                 extra=(("cnt", "", "COUNT", "exact"),))
 ref = compile_plan(plan, None)(tables)
+auto = (jax.sharding.AxisType.Auto,) * 2
+m12 = jax.make_mesh((1, 2), ("data", "model"), axis_types=auto)
+m22 = jax.make_mesh((2, 2), ("data", "model"), axis_types=auto)
 runs = [
-    ("1x2", compile_plan(plan, make_mesh((1, 2), ("data", "model")))),
-    ("2x2", compile_plan(plan, make_mesh((2, 2), ("data", "model")))),
-    ("1x2-slab", compile_plan(plan, make_mesh((1, 2), ("data", "model")),
+    ("1x2", compile_plan(plan, m12)),
+    ("2x2", compile_plan(plan, m22)),
+    ("1x2-slab", compile_plan(plan, m12,
                               cf_budget_elems=1 << 12)),
 ]
 for name, fn in runs:

@@ -139,6 +139,34 @@ def test_group_cumulants_large_values_no_padding_overflow(rng):
     np.testing.assert_allclose(np.asarray(sk), np.asarray(sr), rtol=5e-3)
 
 
+@pytest.mark.kernels
+@pytest.mark.parametrize("tb", [128, 512])
+def test_group_cumulants_layout_invariant(rng, tb):
+    """A group's sums depend only on its own p != 0 tuples, in stream
+    order: a stream holding a third of the groups, with p = 0 rows of
+    other groups interleaved (the owner-local stream of a sharded plan),
+    gives those groups bit-identical sums.  Group 0 spans several
+    tb-tuple pieces."""
+    n, G = 3000, 300
+    p, v, g = _inputs(rng, n, G)
+    g = np.array(g)
+    g[rng.choice(n, 700, replace=False)] = 0
+    kw = dict(num_groups=G, orders=8, tb=tb, interpret=True)
+    want = np.asarray(group_cumulants.group_cumulant_sums(
+        p, v, jnp.asarray(g), **kw))
+    keep = np.flatnonzero(g % 3 == 0)
+    m = 500
+    at = np.sort(rng.integers(0, keep.size + 1, m))
+    pz = np.insert(np.asarray(p)[keep], at, 0.0)
+    vz = np.insert(np.asarray(v)[keep], at, rng.uniform(0, 5, m))
+    gz = np.insert(g[keep], at, rng.integers(0, G, m))
+    got = np.asarray(group_cumulants.group_cumulant_sums(
+        jnp.asarray(pz, jnp.float32), jnp.asarray(vz, jnp.float32),
+        jnp.asarray(gz, jnp.int32), **kw))
+    kept = np.arange(0, G, 3)
+    np.testing.assert_array_equal(got[kept], want[kept])
+
+
 def test_presorted_operands_bit_equal(rng):
     """group_cumulant_sums with hoisted presort_operands == the
     self-sorting call, bit for bit, including through the ops.py

@@ -95,3 +95,16 @@ def test_kernel_end_to_end_distribution(rng):
     mean = float(jnp.sum(coeffs * jnp.arange(n + 1)))
     assert mean == pytest.approx(float(probs.sum()), rel=1e-3)
     assert float(coeffs.sum()) == pytest.approx(1.0, abs=1e-3)
+
+
+def test_kernel_atan2_every_quadrant():
+    """The CF kernels' polynomial atan2 (Mosaic lowers no atan2/atan)
+    against numpy's, over all four quadrants, the axes and the origin:
+    within 2 f32 ulps of pi, the largest angle it returns."""
+    g = np.concatenate([-np.logspace(-6, 3, 60), [0.0],
+                        np.logspace(-6, 3, 60)]).astype(np.float32)
+    y, x = np.meshgrid(g, g)
+    got = np.asarray(pb_cf.atan2(jnp.asarray(y), jnp.asarray(x)))
+    want = np.arctan2(y.astype(np.float64), x.astype(np.float64))
+    np.testing.assert_allclose(got, want, rtol=0,
+                               atol=2 * np.spacing(np.float32(np.pi)))

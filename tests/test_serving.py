@@ -220,12 +220,11 @@ class TestCacheHitBiteq:
         from conftest import run_sub
         out = run_sub('''
 import jax, numpy as np
-from repro.compat import make_mesh
 from repro.core import enable_x64
 enable_x64()
 from repro.db import tpch
 from repro.db.serving import QueryService
-mesh = make_mesh((2,), ("data",))
+mesh = jax.make_mesh((2,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
 db = tpch.generate(n_orders=48, lines_per_order=4, n_parts=24,
                    n_suppliers=8, n_customers=24, seed=0)
 svc = QueryService(db.tables(), mesh, capacity=16)

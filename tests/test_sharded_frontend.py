@@ -264,7 +264,7 @@ def test_group_ids_sharded_on_mesh(mesh_equiv):
     mesh_equiv("""
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 from repro.db import distributed as dist
 from repro.db import operators as ops
 rng = np.random.default_rng(11)
@@ -499,12 +499,11 @@ def test_shuffle_join_3shard_mesh_skew_and_overflow_poisoning():
     from conftest import run_sub
     run_sub("""
 import jax, jax.numpy as jnp, numpy as np
-from repro.compat import make_mesh
 from repro.core import enable_x64
 enable_x64()
 from repro.db.plans import FKJoin, Scan, compile_plan
 from repro.db.table import Table
-mesh = make_mesh((3,), ("data",))
+mesh = jax.make_mesh((3,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
 rng = np.random.default_rng(5)
 # skewed: every left key hits owner 0 (key % 3 == 0)
 left = Table.from_columns(
@@ -547,12 +546,11 @@ def test_stats_tables_make_jit_buckets_skew_adaptive():
     from conftest import run_sub
     run_sub("""
 import jax, jax.numpy as jnp, numpy as np
-from repro.compat import make_mesh
 from repro.core import enable_x64
 enable_x64()
 from repro.db.plans import FKJoin, Scan, compile_plan
 from repro.db.table import Table
-mesh = make_mesh((3,), ("data",))
+mesh = jax.make_mesh((3,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
 rng = np.random.default_rng(5)
 left = Table.from_columns(
     {"k": jnp.asarray([0, 3, 6, 9, 0, 3, 6, 9, 0, 3, 6, 9])},

@@ -141,12 +141,12 @@ def test_q3_q18_copartitioned_bit_equal_zero_roundtrips(devices):
     from conftest import run_sub
     run_sub("""
 import jax, numpy as np
-from repro.compat import make_mesh
 from repro.core import enable_x64
 enable_x64()
 from repro.db import distributed as dist
 from repro.db import physical as phys, tpch
-mesh = make_mesh((__D__,), ("data",))
+mesh = jax.make_mesh((__D__,), ("data",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 db = tpch.generate(n_orders=48, seed=3)
 for qname, kwargs, opts in (("q3", dict(order_join_budget=4), {}),
                             ("q18", {}, dict(agg_shuffle_budget=4))):

@@ -310,10 +310,11 @@ def test_sumcf_model_window_reduce_model_bit_equal():
 import jax, jax.numpy as jnp, numpy as np
 from functools import partial
 from jax.sharding import PartitionSpec as P
-from repro.compat import make_mesh, shard_map
+from jax import shard_map
 from repro.core import enable_x64, uda
 enable_x64()
-mesh = make_mesh((2,), ("model",))
+mesh = jax.make_mesh((2,), ("model",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 r = np.random.default_rng(0)
 n, G, F = 96, 4, 64
 p = jnp.asarray(r.uniform(0.05, 0.95, n))
