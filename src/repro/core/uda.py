@@ -43,6 +43,7 @@ Finalize replicated.
 """
 from __future__ import annotations
 
+import collections
 import math
 from typing import NamedTuple
 
@@ -626,6 +627,45 @@ def _kernel_accumulate(u: UDA, state, probs, values, gids, max_groups,
                              use_kernel=True, operands=operands)
 
 
+#: Scan bodies by UDA configuration (bounded LRU): see _scan_body.
+_SCAN_BODIES: collections.OrderedDict = collections.OrderedDict()
+_PLAIN = (int, float, bool, str, type(None))
+
+
+def _scan_body(scan_udas, val_index):
+    """The blocked scan's body for this set of streaming UDAs.
+
+    ``lax.scan`` caches its trace on the body function object, and an
+    eager scan compiles whatever jaxpr that trace gives.  A body closure
+    built anew per call therefore re-traces and re-compiles the same scan
+    at every eager accumulate.  A UDA's ``update`` depends only on its
+    type and attributes, so equal configurations share one body object —
+    the same program as before, compiled once.  UDAs with any attribute
+    that is not a plain Python scalar (a traced ``freq_lo``) get a fresh
+    body, as before."""
+    attrs = [vars(u).items() for u in scan_udas.values()]
+    key = None
+    if all(isinstance(v, _PLAIN) for a in attrs for _, v in a):
+        key = tuple((name, type(u), val_index[name],
+                     tuple(sorted((k, type(v), v) for k, v in a)))
+                    for (name, u), a in zip(scan_udas.items(), attrs))
+        body = _SCAN_BODIES.get(key)
+        if body is not None:
+            _SCAN_BODIES.move_to_end(key)
+            return body
+
+    def body(carry, chunk):
+        pc, gc, vc = chunk
+        return {name: u.update(carry[name], pc, vc[val_index[name]], gc)
+                for name, u in scan_udas.items()}, None
+
+    if key is not None:
+        _SCAN_BODIES[key] = body
+        if len(_SCAN_BODIES) > 256:
+            _SCAN_BODIES.popitem(last=False)
+    return body
+
+
 def accumulate(udas, probs, values=None, gids=None, *, max_groups: int = 1,
                states=None, block: int = 8192, kernel: str = "auto",
                cf_operands=None):
@@ -742,11 +782,7 @@ def accumulate(udas, probs, values=None, gids=None, *, max_groups: int = 1,
     g = jnp.pad(gids_full, (0, pad), constant_values=max_groups - 1)
     vs = tuple(jnp.pad(v, (0, pad)) for v in val_arrays)
 
-    def body(carry, chunk):
-        pc, gc, vc = chunk
-        return {name: u.update(carry[name], pc, vc[val_index[name]], gc)
-                for name, u in scan_udas.items()}, None
-
+    body = _scan_body(scan_udas, val_index)
     init = {name: states[name] for name in scan_udas}
     chunks = (p.reshape(-1, bsz), g.reshape(-1, bsz),
               tuple(v.reshape(-1, bsz) for v in vs))

@@ -68,6 +68,7 @@ uses the collective helpers below instead of a per-node step:
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable, Sequence
 
 import jax
@@ -102,6 +103,40 @@ def reset_collective_counts() -> None:
 
 def _count(kind: str) -> None:
     COLLECTIVE_COUNTS[kind] = COLLECTIVE_COUNTS.get(kind, 0) + 1
+
+
+@functools.lru_cache(maxsize=4096)
+def _interned(partial):
+    return partial
+
+
+def reuse_eager_shard_map_programs() -> None:
+    """Let an EAGER shard_map reuse the programs it has compiled before.
+
+    Outside jit, JAX (0.9) runs a shard_map body primitive by primitive,
+    each as ``jax.jit(HashablePartial(prim, params, mesh, specs))``.  The
+    partial is built anew per call and dies with it, and JAX's trace and
+    lowering caches hold their keys weakly, so every primitive of every
+    eager call is traced, lowered and compiled again — the same program
+    hundreds of times over.  Interning the partials (equal partials are
+    the same primitive with the same params on the same mesh) keeps the
+    first one alive, so those caches hit.  The programs that run are the
+    ones that ran before, op by op as before: no result changes by a bit.
+    Jitted shard_maps never take this path.  Idempotent; does nothing on
+    a JAX whose eager shard_map is built differently."""
+    from jax._src import shard_map as sm_impl
+    from jax._src.util import HashablePartial
+    if getattr(sm_impl, "HashablePartial", None) is not HashablePartial:
+        return
+
+    def interned_partial(f, *args, **kwargs):
+        partial = HashablePartial(f, *args, **kwargs)
+        try:
+            return _interned(partial)
+        except TypeError:                   # unhashable params
+            return partial
+
+    sm_impl.HashablePartial = interned_partial
 
 
 def data_rank(axis_names):

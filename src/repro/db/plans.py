@@ -769,6 +769,8 @@ def compile_plan(root: Node, mesh=None, *,
     from . import distributed as dist
 
     mesh_mode = mesh is not None
+    if mesh_mode:
+        dist.reuse_eager_shard_map_programs()
     axes = dist._tuple_axes(mesh, data_axes) if mesh_mode else ()
     shards = 1
     for a in axes:

@@ -1,31 +1,221 @@
 # NOTE: no --xla_force_host_platform_device_count here (smoke tests and
 # benches must see 1 device; only launch/dryrun pins 512).  Multi-device
-# tests spawn subprocesses with their own XLA_FLAGS.
+# checks run in subprocesses with their own XLA_FLAGS (`mesh_check`).
+import dataclasses
+import faulthandler
+import json
 import os
+import signal
 import subprocess
 import sys
+import tempfile
+import time
 
 import jax
 import pytest
 
+from mesh_runner import assert_biteq  # noqa: F401  (re-exported)
 from repro.core import enable_x64
 
 enable_x64()  # the PGF engine's exactness tests need f64 on CPU
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+TESTS = os.path.join(ROOT, "tests")
+
+#: Seconds each phase (setup, call, teardown) of a test may take before it
+#: fails with its stack: about 5x the slowest in-process test (~37 s under
+#: the suite's 6 workers).  A test that waits for a subprocess gets that
+#: subprocess's own limit on top.
+TIME_LIMIT = 180
 
 
-def run_sub(script: str, devices: int = 2) -> str:
-    """Run a test script in a subprocess with its own multi-device CPU
-    XLA_FLAGS (the conftest pins the parent process to 1 device) — the ONE
-    copy of the boilerplate shared by every `multidevice` test module."""
-    env = dict(os.environ,
-               XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
-               PYTHONPATH=os.path.join(ROOT, "src"))
-    out = subprocess.run([sys.executable, "-c", script], env=env,
-                         capture_output=True, text=True, timeout=600)
-    assert out.returncode == 0, out.stderr[-3000:]
-    return out.stdout
+class TimeLimitExceeded(Exception):
+    """A test phase outran its time limit."""
+
+
+def _on_alarm(signum, frame):
+    raise TimeLimitExceeded("the test outran its time limit (stacks of "
+                            "all threads are on stderr)")
+
+
+def _arm(seconds: float) -> None:
+    if seconds > 0:
+        signal.setitimer(signal.ITIMER_REAL, seconds)
+        faulthandler.dump_traceback_later(seconds, exit=False)
+    else:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        faulthandler.cancel_dump_traceback_later()
+
+
+def extend_time_limit(seconds: float) -> None:
+    """Add ``seconds`` to the running test phase's limit (for a
+    subprocess that has its own, shorter limit)."""
+    remaining, _ = signal.getitimer(signal.ITIMER_REAL)
+    if remaining > 0:
+        _arm(remaining + seconds)
+
+
+def _limited_phase(item):
+    previous = signal.signal(signal.SIGALRM, _on_alarm)
+    _arm(TIME_LIMIT)
+    try:
+        return (yield)
+    finally:
+        _arm(0)
+        signal.signal(signal.SIGALRM, previous or signal.SIG_DFL)
+
+
+pytest_runtest_setup = pytest.hookimpl(wrapper=True)(_limited_phase)
+pytest_runtest_call = pytest.hookimpl(wrapper=True)(_limited_phase)
+pytest_runtest_teardown = pytest.hookimpl(wrapper=True)(_limited_phase)
+
+
+def _subprocess_env(devices: int) -> dict:
+    return dict(os.environ,
+                XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
+                PYTHONPATH=os.pathsep.join([os.path.join(ROOT, "src"), TESTS]))
+
+
+# ------------------------------------------------- batched multi-device checks
+@dataclasses.dataclass(frozen=True)
+class MeshCheck:
+    """One multi-device check script of a test module.  ``seconds`` is its
+    measured cost inside its batch on the CPU; a batch may take 3x the sum
+    of its checks' costs, plus START_SECONDS."""
+    devices: int
+    script: str
+    seconds: float
+
+
+#: A batch subprocess's allowance for starting Python and importing JAX.
+START_SECONDS = 30
+
+
+@dataclasses.dataclass(frozen=True)
+class Outcome:
+    stdout: str
+    error: str | None       # traceback, or why the script did not finish
+
+
+class Batch:
+    """ONE subprocess running ``checks`` (name -> script) on ``devices``
+    host devices (see ``mesh_runner.py``), started at once; ``outcomes``
+    waits for it.  A batch cut by ``timeout`` (counted from its start)
+    fails the script it was running and the ones after it; the outcomes
+    before it stand."""
+
+    def __init__(self, checks: dict, devices: int, timeout: float):
+        self.names, self.timeout = list(checks), timeout
+        self._dir = tempfile.TemporaryDirectory()
+        self._results = os.path.join(self._dir.name, "results.jsonl")
+        batch = os.path.join(self._dir.name, "batch.json")
+        with open(batch, "w") as f:
+            json.dump(dict(devices=devices, checks=list(checks.items())), f)
+        self._stderr = open(os.path.join(self._dir.name, "stderr"), "w+")
+        self._deadline = time.monotonic() + timeout
+        self._proc = subprocess.Popen(
+            [sys.executable, os.path.join(TESTS, "mesh_runner.py"), batch,
+             self._results], env=_subprocess_env(devices),
+            stdout=subprocess.DEVNULL, stderr=self._stderr)
+        self._outcomes = None
+
+    def outcomes(self) -> dict:
+        if self._outcomes is None:
+            left = max(self._deadline - time.monotonic(), 0)
+            extend_time_limit(left)
+            try:
+                code = self._proc.wait(timeout=left)
+                self._stderr.seek(0)
+                cut = (f"the batch subprocess exited with code {code}:\n"
+                       f"{self._stderr.read()[-3000:]}")
+            except subprocess.TimeoutExpired:
+                cut = (f"the batch subprocess outran its limit of "
+                       f"{self.timeout} s")
+            outcomes = {}
+            if os.path.exists(self._results):
+                with open(self._results) as f:
+                    for line in f:
+                        r = json.loads(line)
+                        outcomes[r["name"]] = Outcome(r["stdout"], r["error"])
+            pending = [n for n in self.names if n not in outcomes]
+            for i, name in enumerate(pending):
+                outcomes[name] = Outcome("", (
+                    f"{cut}\n(while {name} ran)" if i == 0 else
+                    f"not run: {cut}\n(while {pending[0]} ran)"))
+            self._outcomes = outcomes
+            self.close()
+        return self._outcomes
+
+    def close(self) -> None:
+        if self._proc.poll() is None:
+            self._proc.kill()
+            self._proc.wait()
+        self._stderr.close()
+        self._dir.cleanup()
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _mesh_batches(request):
+    """Start the module's multi-device batches — one subprocess per device
+    count, holding the module's selected ``MESH_CHECKS`` of that count —
+    as the module starts, so they run beside its in-process tests."""
+    table = getattr(request.module, "MESH_CHECKS", {})
+    selected = {item.name for item in request.session.items
+                if item.module is request.module}
+    by_devices: dict = {}
+    for name, c in table.items():
+        if name in selected:
+            by_devices.setdefault(c.devices, {})[name] = c
+    batches = {d: Batch({n: c.script for n, c in checks.items()}, d,
+                        timeout=3 * sum(c.seconds for c in checks.values())
+                        + START_SECONDS)
+               for d, checks in by_devices.items()}
+    yield batches
+    for b in batches.values():
+        b.close()
+
+
+@pytest.fixture
+def mesh_check(request, _mesh_batches):
+    """Wait for this test's entry of its module's ``MESH_CHECKS`` (keyed by
+    the test's node name, e.g. ``test_x[3]``) and return its stdout; fail
+    with its traceback."""
+    def check() -> str:
+        name = request.node.name
+        devices = request.module.MESH_CHECKS[name].devices
+        outcome = _mesh_batches[devices].outcomes()[name]
+        if outcome.error is not None:
+            pytest.fail(f"{name} on {devices} devices:\n{outcome.error}",
+                        pytrace=False)
+        return outcome.stdout
+    return check
+
+
+# mesh-equivalence checks: `compile_plan(root, mesh)` promises BIT-IDENTICAL
+# results to the single-device compile (the canonical-chunk fold tree,
+# db/plans.py), asserted exactly — shapes, dtypes and every bit of every leaf.
+_EQUIV_EPILOGUE = '''
+if "pairs" not in dir():
+    # default shape: the setup defined `plan` and `tables`
+    pairs = [("plan", compile_plan(plan, None)(tables),
+              compile_plan(plan, mesh)(tables))]
+for name, ref, got in pairs:
+    assert_biteq(name, ref, got, nan_equal=False)
+print("BITEQ OK")
+'''
+
+
+def equiv_check(setup: str, *, seconds: float, devices: int = 2
+                ) -> MeshCheck:
+    """A MeshCheck that compile_plan on the 1-D data mesh (``mesh``) is
+    bit-equal to the single-device compile.  ``setup`` (x64 on) defines
+    either ``plan`` and ``tables``, or a ``pairs`` list of (name,
+    ref_pytree, got_pytree); it sees ``mesh``, ``tpch``, every plan Node,
+    ``compile_plan``, ``Table`` and ``memo``.  ``__DEVICES__`` in it is
+    replaced by the device count."""
+    script = "enable_x64()\n" + setup + _EQUIV_EPILOGUE
+    return MeshCheck(devices, script.replace("__DEVICES__", str(devices)),
+                     seconds)
 
 
 @pytest.fixture(autouse=True, scope="module")
@@ -46,67 +236,3 @@ def _flat_compiler_footprint():
 def rng():
     import numpy as np
     return np.random.default_rng(0)
-
-
-# --------------------------------------------------- mesh-equivalence harness
-# `compile_plan(root, mesh)` promises BIT-IDENTICAL results to the
-# single-device compile (the canonical-chunk fold tree, db/plans.py).  The
-# harness runs a setup script under a multi-device CPU subprocess and
-# asserts exact equality — shapes, dtypes and every bit of every leaf.
-_MESH_EQUIV_TEMPLATE = '''
-import jax, jax.numpy as jnp, numpy as np
-from repro.core import enable_x64
-enable_x64()
-from repro.db import tpch
-from repro.db.plans import (FKJoin, GroupAgg, Map, Project, ReweightGreater,
-                            Scan, Select, compile_plan)
-from repro.db.table import Table
-mesh = jax.make_mesh((__DEVICES__,), ("data",),
-                     axis_types=(jax.sharding.AxisType.Auto,))
-
-__SETUP__
-
-if "pairs" not in dir():
-    # default harness shape: setup defined `plan` and `tables`
-    pairs = [("plan", compile_plan(plan, None)(tables),
-              compile_plan(plan, mesh)(tables))]
-
-for name, ref, got in pairs:
-    la, ta = jax.tree.flatten(ref)
-    lb, tb = jax.tree.flatten(got)
-    assert str(ta) == str(tb), (name, str(ta), str(tb))
-    for i, (a, b) in enumerate(zip(la, lb)):
-        a, b = np.asarray(a), np.asarray(b)
-        assert a.shape == b.shape and a.dtype == b.dtype, \\
-            (name, i, a.shape, b.shape, a.dtype, b.dtype)
-        if not np.array_equal(a, b):
-            eq = a == b
-            f = a.astype(np.float64, copy=False)
-            g = b.astype(np.float64, copy=False)
-            eq |= np.isnan(f) & np.isnan(g)      # NaN == NaN for the diff
-            bad = np.flatnonzero(~eq)
-            raise AssertionError(
-                name + " leaf " + str(i) + ": " + str(bad.size)
-                + " of " + str(a.size) + " elements differ, max |d| = "
-                + str(np.nanmax(np.abs(f - g))))
-print("BITEQ OK")
-'''
-
-
-@pytest.fixture
-def mesh_equiv():
-    """Run `setup` under a multi-device CPU subprocess and assert that
-    compile_plan on the 1-D data mesh is bit-equal to the single-device
-    compile.  `setup` either defines `plan` and `tables`, or a `pairs`
-    list of (name, ref_pytree, got_pytree) for query-level checks; the
-    subprocess exposes `mesh`, `tpch`, every plan Node and `compile_plan`.
-    """
-    def check(setup: str, devices: int = 2) -> str:
-        # setup first so its own __DEVICES__ occurrences resolve too
-        script = (_MESH_EQUIV_TEMPLATE
-                  .replace("__SETUP__", setup)
-                  .replace("__DEVICES__", str(devices)))
-        out = run_sub(script, devices=devices)
-        assert "BITEQ OK" in out
-        return out
-    return check

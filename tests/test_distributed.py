@@ -1,17 +1,11 @@
-"""Multi-device behaviour via subprocesses (own XLA_FLAGS, 8 host devices):
-shard_map query execution == single-device reference; compressed psum;
-elastic mesh degradation."""
-import functools
-
+"""Multi-device behaviour in one subprocess with 8 host devices (conftest
+`mesh_check`): shard_map query execution == single-device reference;
+compressed psum; elastic mesh degradation."""
 import pytest
 
-from conftest import run_sub as _run_sub
+from conftest import MeshCheck
 
-run_sub = functools.partial(_run_sub, devices=8)
-
-
-def test_distributed_query_step_matches_reference():
-    out = run_sub("""
+_QUERY_STEP = """
 import numpy as np, jax, jax.numpy as jnp
 from repro.db import distributed as dist
 from repro.core import poisson_binomial as pb
@@ -33,12 +27,9 @@ assert float(jnp.max(jnp.abs(conf - ref_conf))) < 1e-5
 mu_ref = np.bincount(g, v * p, G)
 assert float(jnp.max(jnp.abs(normal[:, 0] - mu_ref))) < 1e-3
 print("OK")
-""")
-    assert "OK" in out
+"""
 
-
-def test_compressed_psum_under_shard_map():
-    out = run_sub("""
+_COMPRESSED_PSUM = """
 import numpy as np, jax, jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import PartitionSpec as P
@@ -57,12 +48,9 @@ true_sum = g.mean(0)
 rel = float(jnp.abs(avg[0] - true_sum).max() / (jnp.abs(true_sum).max()))
 assert rel < 0.05, rel
 print("OK")
-""")
-    assert "OK" in out
+"""
 
-
-def test_elastic_degrade_mesh():
-    out = run_sub("""
+_ELASTIC_DEGRADE = """
 import jax
 from repro.train.elastic import degrade_mesh, scale_batch
 # full fleet: 8 devices -> (2, 4) mesh? model capped at 4
@@ -73,14 +61,9 @@ m2 = degrade_mesh(jax.devices()[:5], prefer_model=4)
 assert m2.shape["model"] == 4 and m2.shape["data"] == 1, dict(m2.shape)
 assert scale_batch(64, m) == 32
 print("OK")
-""")
-    assert "OK" in out
+"""
 
-
-def test_sharded_train_step_runs_and_matches_single_device():
-    """A reduced-arch train step under a 4x2 mesh with the production
-    sharding rules == the same step on one device."""
-    out = run_sub("""
+_SHARDED_TRAIN_STEP = """
 import numpy as np, jax, jax.numpy as jnp
 from repro.configs import get_reduced
 from repro.models import api
@@ -111,5 +94,36 @@ d = max(float(jnp.abs(jnp.asarray(a, jnp.float32) - jnp.asarray(b, jnp.float32))
         for a, b in zip(jax.tree.leaves(p1), jax.tree.leaves(p2)))
 assert d < 2e-5, d
 print("OK", float(m1["loss"]))
-""")
-    assert "OK" in out
+"""
+
+MESH_CHECKS = {
+    "test_distributed_query_step_matches_reference": MeshCheck(
+        8, _QUERY_STEP, seconds=5),
+    "test_compressed_psum_under_shard_map": MeshCheck(
+        8, _COMPRESSED_PSUM, seconds=5),
+    "test_elastic_degrade_mesh": MeshCheck(8, _ELASTIC_DEGRADE, seconds=5),
+    "test_sharded_train_step_runs_and_matches_single_device": MeshCheck(
+        8, _SHARDED_TRAIN_STEP, seconds=15),
+}
+
+
+@pytest.mark.multidevice
+def test_distributed_query_step_matches_reference(mesh_check):
+    assert "OK" in mesh_check()
+
+
+@pytest.mark.multidevice
+def test_compressed_psum_under_shard_map(mesh_check):
+    assert "OK" in mesh_check()
+
+
+@pytest.mark.multidevice
+def test_elastic_degrade_mesh(mesh_check):
+    assert "OK" in mesh_check()
+
+
+@pytest.mark.multidevice
+def test_sharded_train_step_runs_and_matches_single_device(mesh_check):
+    """A reduced-arch train step under a 4x2 mesh with the production
+    sharding rules == the same step on one device."""
+    assert "OK" in mesh_check()

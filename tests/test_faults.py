@@ -21,6 +21,7 @@ import jax
 import numpy as np
 import pytest
 
+from conftest import MeshCheck, assert_biteq
 from repro.db import tpch
 from repro.db.plans import (GroupAgg, RetryExhausted, RetryPolicy, Scan,
                             compile_plan, run_plan)
@@ -46,19 +47,6 @@ def _db():
                          n_suppliers=8, n_customers=24, seed=0)
 
 
-def _assert_biteq(name, ref, got):
-    la, ta = jax.tree.flatten(ref)
-    lb, tb = jax.tree.flatten(got)
-    assert str(ta) == str(tb), (name, str(ta), str(tb))
-    for i, (a, b) in enumerate(zip(la, lb)):
-        a, b = np.asarray(a), np.asarray(b)
-        assert a.shape == b.shape and a.dtype == b.dtype, (name, i)
-        if not np.array_equal(a, b):
-            f = a.astype(np.float64, copy=False)
-            g = b.astype(np.float64, copy=False)
-            assert ((a == b) | (np.isnan(f) & np.isnan(g))).all(), (name, i)
-
-
 # ===================================================== clean-path report
 def test_clean_run_report_is_clean_and_free():
     """Happy path: report collection flags nothing and changes no bit."""
@@ -67,7 +55,7 @@ def test_clean_run_report_is_clean_and_free():
                     "l_quantity", "SUM", 8, "normal")
     ref = compile_plan(root)(db.tables())
     out, rep = compile_plan(root, with_report=True)(db.tables())
-    _assert_biteq("clean", ref, out)
+    assert_biteq("clean", ref, out)
     assert rep.issues() == {} and rep.ok()
     assert rep.describe() == "clean"
     assert rep.overflow_total() == 0
@@ -111,7 +99,7 @@ def test_kappa_escalation_converges_bit_equal():
     big = GroupAgg(Scan("lineitem"), ("l_orderkey",), "l_quantity",
                    "MAX", 64, kappa=scale)
     ref = compile_plan(big)(db.tables())
-    _assert_biteq("kappa", ref, out)
+    assert_biteq("kappa", ref, out)
 
 
 def test_group_overflow_escalation():
@@ -131,7 +119,7 @@ def test_group_overflow_escalation():
     ref = compile_plan(GroupAgg(Scan("lineitem"), ("l_orderkey",),
                                 "l_quantity", "SUM", 16 * scale,
                                 "normal"))(db.tables())
-    _assert_biteq("groups", ref, out)
+    assert_biteq("groups", ref, out)
 
 
 def test_retry_exhausted_carries_report():
@@ -160,7 +148,7 @@ def test_streamed_transient_fault_resumes_bit_equal():
         with faults.inject(faults.FaultPlan(transfer_calls={10})) as fp:
             got = call(plan_opts=opts)
         assert fp.consumed(), qname
-        _assert_biteq(qname, ref, got)
+        assert_biteq(qname, ref, got)
         (fi, fw), = [(i, w) for i, w, _r, f in fp.log if f]
         after = [w for i, w, _r, f in fp.log if i > fi]
         # ship order is monotone within one wave loop; a later loop
@@ -187,7 +175,7 @@ def test_streamed_fault_during_prefetch_no_double_file():
                           plan_opts=dict(device_row_budget=64,
                                          stream_wave_chunks=1))
         assert fp.consumed(), occ
-        _assert_biteq(f"q1/occ{occ}", ref, got)
+        assert_biteq(f"q1/occ{occ}", ref, got)
 
 
 def test_streamed_fault_exhausts_inloop_retries_annotated():
@@ -216,7 +204,7 @@ def test_wave_halving_retry():
     assert rep.waves["attempts"] == 2
     assert rep.final_params["stream_wave_chunks"] == 2
     ref = compile_plan(root, device_row_budget=64)(db.tables())
-    _assert_biteq("halved", ref, out)
+    assert_biteq("halved", ref, out)
 
 
 # ==================================== multi-device overflow + silent NaN
@@ -286,23 +274,10 @@ fn3 = jax.jit(L.compile_plan(root, mesh, with_report=True,
                              **opts))
 out3, rep3 = fn3(tables)
 assert rep3.issues() == {}
-for a, b in zip(jax.tree.leaves(out2), jax.tree.leaves(out3)):
-    assert np.array_equal(np.asarray(a), np.asarray(b))
+assert_biteq("healed", out3, out2, nan_equal=False)
 assert np.isfinite(np.asarray(out2["sum"][0])[np.asarray(out2["valid"])]).all()
 print("OVERFLOW RETRY OK")
 """
-
-
-@pytest.mark.multidevice
-@pytest.mark.parametrize("copart", [False, True])
-def test_overflow_retry_3shard(copart):
-    """An overflowing 3-shard exchange under jit: detected in the report
-    (through a boolean output too), healed by RetryPolicy in <=3
-    attempts, bit-equal to a run at the final escalated parameters —
-    for both the ShuffleJoin and CoPartitionedJoin lowerings."""
-    from conftest import run_sub
-    out = run_sub(_OVERFLOW_SCRIPT % dict(copart=copart), devices=3)
-    assert "OVERFLOW RETRY OK" in out
 
 
 _FUZZ_SCRIPT = r"""
@@ -342,8 +317,7 @@ def trial(seed, copart):
     # sender's local rows, overflow impossible
     big = jax.jit(L.compile_plan(root, mesh, shuffle_slack=3.0, **opts))
     ref = big(tables)
-    for a, b in zip(jax.tree.leaves(ref), jax.tree.leaves(out)):
-        assert np.array_equal(np.asarray(a), np.asarray(b)), (seed, copart)
+    assert_biteq(f"seed={seed} copart={copart}", ref, out, nan_equal=False)
 
 for seed in %(seeds)s:
     for copart in (False, True):
@@ -352,15 +326,32 @@ print("CONVERGENCE FUZZ OK")
 """
 
 
+MESH_CHECKS = {
+    **{f"test_overflow_retry_3shard[{copart}]": MeshCheck(
+        3, _OVERFLOW_SCRIPT % dict(copart=copart), seconds=10)
+       for copart in (False, True)},
+    "test_retry_convergence_fuzz_seeded": MeshCheck(
+        3, _FUZZ_SCRIPT % dict(seeds=[0, 1, 2]), seconds=30),
+}
+
+
 @pytest.mark.multidevice
-def test_retry_convergence_fuzz_seeded():
+@pytest.mark.parametrize("copart", [False, True])
+def test_overflow_retry_3shard(mesh_check, copart):
+    """An overflowing 3-shard exchange under jit: detected in the report
+    (through a boolean output too), healed by RetryPolicy in <=3
+    attempts, bit-equal to a run at the final escalated parameters —
+    for both the ShuffleJoin and CoPartitionedJoin lowerings."""
+    assert "OVERFLOW RETRY OK" in mesh_check()
+
+
+@pytest.mark.multidevice
+def test_retry_convergence_fuzz_seeded(mesh_check):
     """Seeded-fallback fuzz (always runs): skewed key distributions on a
     3-shard mesh converge under RetryPolicy within max_attempts and
     match the oversized-from-the-start run bit for bit, for both
     ShuffleJoin and CoPartitionedJoin."""
-    from conftest import run_sub
-    out = run_sub(_FUZZ_SCRIPT % dict(seeds=[0, 1, 2]), devices=3)
-    assert "CONVERGENCE FUZZ OK" in out
+    assert "CONVERGENCE FUZZ OK" in mesh_check()
 
 
 @pytest.mark.multidevice
@@ -371,14 +362,16 @@ def test_retry_convergence_fuzz_hypothesis():
     trial harness."""
     hyp = pytest.importorskip("hypothesis")
     from hypothesis import strategies as st
-    from conftest import run_sub
+    from conftest import Batch
 
     @hyp.given(st.lists(st.integers(0, 10_000), min_size=2, max_size=4,
                         unique=True))
     @hyp.settings(max_examples=3, deadline=None)
     def check(seeds):
-        out = run_sub(_FUZZ_SCRIPT % dict(seeds=seeds), devices=3)
-        assert "CONVERGENCE FUZZ OK" in out
+        out = Batch({"fuzz": _FUZZ_SCRIPT % dict(seeds=seeds)},
+                    devices=3, timeout=120).outcomes()["fuzz"]
+        assert out.error is None, out.error
+        assert "CONVERGENCE FUZZ OK" in out.stdout
 
     check()
 
@@ -425,8 +418,8 @@ def test_queryservice_healed_replay():
     out2, info2 = svc.submit(root)
     assert info2["attempts"] == 1                   # replays final_params
     assert info2["hit"] and svc.cache.misses == misses_after_heal
-    _assert_biteq("healed-replay", out1, out2)
+    assert_biteq("healed-replay", out1, out2)
     # the healed hit also equals a from-scratch escalated run
     out3, _ = run_plan(root, db.tables(),
                        policy=RetryPolicy(max_attempts=4))
-    _assert_biteq("healed-vs-fresh", out1, out3)
+    assert_biteq("healed-vs-fresh", out1, out3)

@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import run_sub
+from conftest import MeshCheck
 from repro.core import uda
 from repro.core.config import default_float
 from repro.core.pgf import possible_worlds_pgf
@@ -312,11 +312,8 @@ def test_traced_freq_lo_requires_freq_cnt(rng):
 
 
 # --------------------------------------------------- mesh equivalence
-@pytest.mark.multidevice
-def test_groupagg_exact_mesh_equivalence():
-    """Exact GroupAgg on a 2-device mesh == single device, both unchunked
-    and with a slab budget small enough to force multi-pass psum merges."""
-    out = run_sub("""
+# (conftest `mesh_check`: one subprocess per device count)
+_EXACT_MESH = """
 import jax, jax.numpy as jnp
 from repro.core import enable_x64
 enable_x64()
@@ -336,18 +333,9 @@ for got in (compile_plan(plan, mesh)(tables),
                                   jnp.asarray(got[k]))))
         assert d < 1e-9, (k, d)
 print("OK")
-""")
-    assert "OK" in out
+"""
 
-
-@pytest.mark.multidevice
-def test_groupagg_exact_model_sharded_bit_equal():
-    """Exact GroupAgg with a >1-sized MODEL axis — each frequency slab
-    split into per-rank windows via the traced-freq_lo kernel path and
-    reassembled by one tiled all-gather — must be BIT-identical to the
-    unsharded compile, on a pure-model 1x2 mesh, a 2x2 data x model mesh,
-    and under a slab budget that forces multi-pass accumulation."""
-    out = run_sub("""
+_EXACT_MODEL_SHARDED = """
 import jax, jax.numpy as jnp, numpy as np
 from repro.core import enable_x64
 enable_x64()
@@ -371,12 +359,33 @@ runs = [
 for name, fn in runs:
     got = fn(tables)
     for k in ("exact", "cnt", "confidence"):
-        a, b = np.asarray(ref[k]), np.asarray(got[k])
-        assert np.array_equal(a, b), (name, k,
-                                      float(np.max(np.abs(a - b))))
+        assert_biteq(name + "/" + k, ref[k], got[k], nan_equal=False)
 print("BITEQ OK")
-""", devices=4)
-    assert "BITEQ OK" in out
+"""
+
+MESH_CHECKS = {
+    "test_groupagg_exact_mesh_equivalence": MeshCheck(2, _EXACT_MESH,
+                                                      seconds=35),
+    "test_groupagg_exact_model_sharded_bit_equal": MeshCheck(
+        4, _EXACT_MODEL_SHARDED, seconds=105),
+}
+
+
+@pytest.mark.multidevice
+def test_groupagg_exact_mesh_equivalence(mesh_check):
+    """Exact GroupAgg on a 2-device mesh == single device, both unchunked
+    and with a slab budget small enough to force multi-pass psum merges."""
+    assert "OK" in mesh_check()
+
+
+@pytest.mark.multidevice
+def test_groupagg_exact_model_sharded_bit_equal(mesh_check):
+    """Exact GroupAgg with a >1-sized MODEL axis — each frequency slab
+    split into per-rank windows via the traced-freq_lo kernel path and
+    reassembled by one tiled all-gather — must be BIT-identical to the
+    unsharded compile, on a pure-model 1x2 mesh, a 2x2 data x model mesh,
+    and under a slab budget that forces multi-pass accumulation."""
+    assert "BITEQ OK" in mesh_check()
 
 
 def test_presorted_operands_bit_equal():

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import MeshCheck, assert_biteq
 from repro.db import serving, tpch
 from repro.db.plans import (GroupAgg, LRUCache, Scan, Select, compile_plan,
                             plan_key, plan_params, set_wave_cache_capacity,
@@ -45,19 +46,6 @@ def _bounded_compile_cache():
 def _db():
     return tpch.generate(n_orders=48, lines_per_order=4, n_parts=24,
                          n_suppliers=8, n_customers=24, seed=0)
-
-
-def _assert_biteq(name, ref, got):
-    la, ta = jax.tree.flatten(ref)
-    lb, tb = jax.tree.flatten(got)
-    assert str(ta) == str(tb), (name, str(ta), str(tb))
-    for i, (a, b) in enumerate(zip(la, lb)):
-        a, b = np.asarray(a), np.asarray(b)
-        assert a.shape == b.shape and a.dtype == b.dtype, (name, i)
-        if not np.array_equal(a, b):
-            f = a.astype(np.float64, copy=False)
-            g = b.astype(np.float64, copy=False)
-            assert ((a == b) | (np.isnan(f) & np.isnan(g))).all(), (name, i)
 
 
 # ------------------------------------------------------ structural plan keys
@@ -204,7 +192,7 @@ class TestCacheHitBiteq:
         for name, p in tpch.serving_plans().items():
             out, info = svc.submit(p)
             assert info["hit"], name
-            _assert_biteq(name, cold[name], out)
+            assert_biteq(name, cold[name], out)
 
     @pytest.mark.outofcore
     def test_streamed(self):
@@ -213,31 +201,11 @@ class TestCacheHitBiteq:
         cold, i0 = svc.submit(tpch.q1_plan())
         warm, i1 = svc.submit(tpch.q1_plan())
         assert not i0["hit"] and i1["hit"]
-        _assert_biteq("q1-streamed", cold, warm)
+        assert_biteq("q1-streamed", cold, warm)
 
     @pytest.mark.multidevice
-    def test_mesh(self):
-        from conftest import run_sub
-        out = run_sub('''
-import jax, numpy as np
-from repro.core import enable_x64
-enable_x64()
-from repro.db import tpch
-from repro.db.serving import QueryService
-mesh = jax.make_mesh((2,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
-db = tpch.generate(n_orders=48, lines_per_order=4, n_parts=24,
-                   n_suppliers=8, n_customers=24, seed=0)
-svc = QueryService(db.tables(), mesh, capacity=16)
-for name, plan in tpch.serving_plans().items():
-    cold, i0 = svc.submit(plan)
-    assert not i0["hit"], name
-    warm, i1 = svc.submit(plan)
-    assert i1["hit"], name
-    for a, b in zip(jax.tree.leaves(cold), jax.tree.leaves(warm)):
-        assert np.asarray(a).tobytes() == np.asarray(b).tobytes(), name
-print("BITEQ OK")
-''')
-        assert "BITEQ OK" in out
+    def test_mesh(self, mesh_check):
+        assert "BITEQ OK" in mesh_check()
 
 
 # ----------------------------------------------------- parameterized sweeps
@@ -261,7 +229,7 @@ class TestSweep:
             seq = jax.jit(svc.cache.entry(fam, None, jit=False)[0].fn)
             for i in range(n):
                 point = {k: v[i] for k, v in batch.items()}
-                _assert_biteq(f"{name}[{i}]",
+                assert_biteq(f"{name}[{i}]",
                               seq(tables, point),
                               jax.tree.map(lambda l: l[i], out))
 
@@ -273,7 +241,7 @@ class TestSweep:
             a, ia = whole.sweep(fam, batch)
             b, ib = chunked.sweep(fam, batch)
             assert ia["launches"] == 1 and ib["launches"] > 1
-            _assert_biteq(name, a, b)
+            assert_biteq(name, a, b)
 
     def test_resweep_hits_cache(self):
         tables = _db().tables()
@@ -341,3 +309,26 @@ def test_serving_stats_counters():
     assert s["requests"] == 3 and s["cache_hits"] == 1
     assert s["batched_requests"] == 1 and s["batched_points"] == 4
     assert s["hit_rate"] == pytest.approx(1 / 3, abs=1e-3)
+
+
+# ------------------------------------------------ the multi-device check
+# (conftest `mesh_check`: one subprocess per device count)
+MESH_CHECKS = {"test_mesh": MeshCheck(2, """
+import jax, numpy as np
+from repro.core import enable_x64
+enable_x64()
+from repro.db import tpch
+from repro.db.serving import QueryService
+mesh = jax.make_mesh((2,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
+db = tpch.generate(n_orders=48, lines_per_order=4, n_parts=24,
+                   n_suppliers=8, n_customers=24, seed=0)
+svc = QueryService(db.tables(), mesh, capacity=16)
+for name, plan in tpch.serving_plans().items():
+    cold, i0 = svc.submit(plan)
+    assert not i0["hit"], name
+    warm, i1 = svc.submit(plan)
+    assert i1["hit"], name
+    for a, b in zip(jax.tree.leaves(cold), jax.tree.leaves(warm)):
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes(), name
+print("BITEQ OK")
+""", seconds=10)}

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import MeshCheck, equiv_check
 from repro.db import operators as ops
 from repro.db.plans import FKJoin, GroupAgg, Scan, compile_plan
 from repro.db.table import Table
@@ -202,14 +203,7 @@ def test_fk_join_possible_worlds_parity(rng):
 
 
 # ------------------------------------------------- sharded-path parity
-@pytest.mark.multidevice
-def test_fk_join_sharded_worlds_parity(mesh_equiv):
-    """FKJoin through the sharded frontend: bit-equal to the single-device
-    compile, possible-worlds parity for the carried probabilities, and the
-    same answers when a tiny join_gather_budget lowers the join to the
-    shuffle-partitioned strategy (NO replicated fallback exists anymore —
-    asserted against the physical plan)."""
-    mesh_equiv("""
+_FK_JOIN_PARITY = """
 import numpy as np
 from repro.db import physical as phys
 rng = np.random.default_rng(7)
@@ -254,14 +248,20 @@ assert p_out.shape[0] >= lp.size and not p_out[lp.size:].any()
 np.testing.assert_allclose(p_out[:lp.size], marg, atol=1e-12)
 for i in np.flatnonzero(np.asarray(got.valid)):
     assert int(got["pay"][i]) == 10 + int(got["k"][i])
-""")
+"""
 
 
 @pytest.mark.multidevice
-def test_group_ids_sharded_on_mesh(mesh_equiv):
-    """The real shard_map path of db.distributed.group_ids_sharded against
-    the single-device oracle, including near-capacity cardinality."""
-    mesh_equiv("""
+def test_fk_join_sharded_worlds_parity(mesh_check):
+    """FKJoin through the sharded frontend: bit-equal to the single-device
+    compile, possible-worlds parity for the carried probabilities, and the
+    same answers when a tiny join_gather_budget lowers the join to the
+    shuffle-partitioned strategy (NO replicated fallback exists anymore —
+    asserted against the physical plan)."""
+    assert "BITEQ OK" in mesh_check()
+
+
+_GROUP_IDS_ON_MESH = """
 import numpy as np
 from jax.sharding import PartitionSpec as P
 from jax import shard_map
@@ -281,7 +281,14 @@ def f(tt):
 ids, codes, gv = shard_map(f, mesh=mesh, in_specs=(P("data"),),
                            out_specs=P(), check_vma=False)(t)
 pairs = [("group_ids", (ids_ref, codes_ref, gv_ref), (ids, codes, gv))]
-""")
+"""
+
+
+@pytest.mark.multidevice
+def test_group_ids_sharded_on_mesh(mesh_check):
+    """The real shard_map path of db.distributed.group_ids_sharded against
+    the single-device oracle, including near-capacity cardinality."""
+    assert "BITEQ OK" in mesh_check()
 
 
 # ------------------------------------------- shuffle-exchange protocol
@@ -487,17 +494,7 @@ def test_bucket_slots_roundtrip_fuzz(seed):
     assert (np.asarray(got)[~np.asarray(sent)] == 0).all()
 
 
-@pytest.mark.multidevice
-def test_shuffle_join_3shard_mesh_skew_and_overflow_poisoning():
-    """On a real 3-device mesh with every key hashing to owner 0: the
-    shuffle-lowered plan is bit-equal to mesh=None.  Eager compiles see
-    the concrete keys and size buckets from the real histogram, so even
-    slack 1.0 cannot overflow (the skew-adaptive capacities); under jit
-    the keys are traced, the slack sizing comes back, and overflowing
-    buckets poison the join probabilities with NaN (accounted, never
-    silently wrong)."""
-    from conftest import run_sub
-    run_sub("""
+_SHUFFLE_3SHARD = """
 import jax, jax.numpy as jnp, numpy as np
 from repro.core import enable_x64
 enable_x64()
@@ -517,34 +514,34 @@ tables = {"L": left, "R": right}
 plan = FKJoin(Scan("L"), Scan("R"), "k", "k", ("pay",))
 ref = compile_plan(plan, None)(tables)
 ok = compile_plan(plan, mesh, join_gather_budget=1)(tables)
-for a, b in zip(jax.tree.leaves(ref), jax.tree.leaves(ok)):
-    assert np.array_equal(np.asarray(a), np.asarray(b))
+assert_biteq("shuffled", ref, ok, nan_equal=False)
 # eager + concrete keys: histogram-sized buckets absorb the skew even at
 # slack 1.0 (no overflow, bit-equal)
 adaptive = compile_plan(plan, mesh, join_gather_budget=1,
                         shuffle_slack=1.0)(tables)
-for a, b in zip(jax.tree.leaves(ref), jax.tree.leaves(adaptive)):
-    assert np.array_equal(np.asarray(a), np.asarray(b))
+assert_biteq("adaptive", ref, adaptive, nan_equal=False)
 # jit: traced keys -> slack 1.0 buckets ceil(local/3) < the skewed
 # demand -> overflow NaN-poisons
 bad = jax.jit(compile_plan(plan, mesh, join_gather_budget=1,
                            shuffle_slack=1.0))(tables)
 assert np.isnan(np.asarray(bad.prob)).all(), np.asarray(bad.prob)
 print("OK")
-""", devices=3)
+"""
 
 
 @pytest.mark.multidevice
-def test_stats_tables_make_jit_buckets_skew_adaptive():
-    """The carried traced-key item: ``compile_plan(stats_tables=...)``
-    hands the lowering concrete stand-in tables, so the key % n_shards
-    histograms size the jit path's buckets OUTSIDE the trace.  The same
-    skewed join that NaN-poisons under jit with flat slack 1.0 buckets
-    (previous test) is bit-equal to mesh=None when the stats tables
-    carry the real key population — and a WRONG histogram still has the
-    NaN overflow guard as the backstop."""
-    from conftest import run_sub
-    run_sub("""
+def test_shuffle_join_3shard_mesh_skew_and_overflow_poisoning(mesh_check):
+    """On a real 3-device mesh with every key hashing to owner 0: the
+    shuffle-lowered plan is bit-equal to mesh=None.  Eager compiles see
+    the concrete keys and size buckets from the real histogram, so even
+    slack 1.0 cannot overflow (the skew-adaptive capacities); under jit
+    the keys are traced, the slack sizing comes back, and overflowing
+    buckets poison the join probabilities with NaN (accounted, never
+    silently wrong)."""
+    assert "OK" in mesh_check()
+
+
+_STATS_TABLES = """
 import jax, jax.numpy as jnp, numpy as np
 from repro.core import enable_x64
 enable_x64()
@@ -567,8 +564,7 @@ ref = compile_plan(plan, None)(tables)
 good = jax.jit(compile_plan(plan, mesh, join_gather_budget=1,
                             shuffle_slack=1.0,
                             stats_tables=tables))(tables)
-for a, b in zip(jax.tree.leaves(ref), jax.tree.leaves(good)):
-    assert np.array_equal(np.asarray(a), np.asarray(b))
+assert_biteq("stats", ref, good, nan_equal=False)
 # unrepresentative stats (uniform keys) undersize owner 0's bucket: the
 # overflow guard still NaN-poisons instead of dropping rows silently
 fake = {"L": Table.from_columns(
@@ -580,4 +576,30 @@ bad = jax.jit(compile_plan(plan, mesh, join_gather_budget=1,
                            stats_tables=fake))(tables)
 assert np.isnan(np.asarray(bad.prob)).any(), np.asarray(bad.prob)
 print("STATS OK")
-""", devices=3)
+"""
+
+
+@pytest.mark.multidevice
+def test_stats_tables_make_jit_buckets_skew_adaptive(mesh_check):
+    """The carried traced-key item: ``compile_plan(stats_tables=...)``
+    hands the lowering concrete stand-in tables, so the key % n_shards
+    histograms size the jit path's buckets OUTSIDE the trace.  The same
+    skewed join that NaN-poisons under jit with flat slack 1.0 buckets
+    (previous test) is bit-equal to mesh=None when the stats tables
+    carry the real key population — and a WRONG histogram still has the
+    NaN overflow guard as the backstop."""
+    assert "STATS OK" in mesh_check()
+
+
+# ------------------------------------------------ the multi-device checks
+# (conftest `mesh_check`: one subprocess per device count)
+MESH_CHECKS = {
+    "test_fk_join_sharded_worlds_parity": equiv_check(_FK_JOIN_PARITY,
+                                                      seconds=10),
+    "test_group_ids_sharded_on_mesh": equiv_check(_GROUP_IDS_ON_MESH,
+                                                  seconds=10),
+    "test_shuffle_join_3shard_mesh_skew_and_overflow_poisoning": MeshCheck(
+        3, _SHUFFLE_3SHARD, seconds=20),
+    "test_stats_tables_make_jit_buckets_skew_adaptive": MeshCheck(
+        3, _STATS_TABLES, seconds=5),
+}

@@ -14,6 +14,7 @@ import jax
 import numpy as np
 import pytest
 
+from conftest import MeshCheck, assert_biteq
 from repro.db import plans as P
 from repro.db import tpch
 from repro.db.plans import (GroupAgg, Scan, Select, compile_plan,
@@ -30,20 +31,6 @@ def _db():
     # must stay resident).
     return tpch.generate(n_orders=48, lines_per_order=4, n_parts=24,
                          n_suppliers=8, n_customers=24, seed=0)
-
-
-def _assert_biteq(name, ref, got):
-    la, ta = jax.tree.flatten(ref)
-    lb, tb = jax.tree.flatten(got)
-    assert str(ta) == str(tb), (name, str(ta), str(tb))
-    for i, (a, b) in enumerate(zip(la, lb)):
-        a, b = np.asarray(a), np.asarray(b)
-        assert a.shape == b.shape and a.dtype == b.dtype, (name, i)
-        if not np.array_equal(a, b):
-            f = a.astype(np.float64, copy=False)
-            g = b.astype(np.float64, copy=False)
-            eq = (a == b) | (np.isnan(f) & np.isnan(g))
-            assert eq.all(), (name, i, a, b)
 
 
 # ------------------------------------------------ single-device streaming
@@ -73,7 +60,7 @@ def test_streamed_bit_equal_resident(qname, prune):
     got = _run_query(db, qname,
                      dict(device_row_budget=_QUERY_BUDGET[qname],
                           stream_prune_columns=prune))
-    _assert_biteq(f"{qname}/prune={prune}", ref, got)
+    assert_biteq(f"{qname}/prune={prune}", ref, got)
 
 
 def _plan_for(qname):
@@ -100,12 +87,12 @@ def test_streamed_bit_equal_disk_backed(qname, tmp_path):
         got = compile_plan(plan,
                            device_row_budget=_QUERY_BUDGET[qname],
                            stream_prune_columns=prune)(disk)
-        _assert_biteq(f"{qname}/disk/prune={prune}", ref, got)
+        assert_biteq(f"{qname}/disk/prune={prune}", ref, got)
     for wc in (1, 3, 8):
         got = compile_plan(plan,
                            device_row_budget=_QUERY_BUDGET[qname],
                            stream_wave_chunks=wc)(disk)
-        _assert_biteq(f"{qname}/disk/wc={wc}", ref, got)
+        assert_biteq(f"{qname}/disk/wc={wc}", ref, got)
 
 
 def test_save_open_roundtrip(tmp_path):
@@ -126,7 +113,7 @@ def test_save_open_roundtrip(tmp_path):
         assert back[k].dtype == ht[k].dtype
     np.testing.assert_array_equal(back.prob, ht.prob)
     np.testing.assert_array_equal(back.valid, ht.valid)
-    _assert_biteq("roundtrip/to_table", ht.to_table(), back.to_table())
+    assert_biteq("roundtrip/to_table", ht.to_table(), back.to_table())
     ram = HostTable.open(str(tmp_path), mmap_mode=None)
     assert not isinstance(ram["a"], np.memmap)
     np.testing.assert_array_equal(ram["a"], ht["a"])
@@ -143,7 +130,7 @@ def test_wave_size_invariance(wave_chunks):
         got = _run_query(db, qname,
                          dict(device_row_budget=64,
                               stream_wave_chunks=wave_chunks))
-        _assert_biteq(f"{qname}/wc{wave_chunks}", ref, got)
+        assert_biteq(f"{qname}/wc{wave_chunks}", ref, got)
 
 
 def test_sync_transfer_matches_double_buffered():
@@ -153,7 +140,7 @@ def test_sync_transfer_matches_double_buffered():
     ref = _run_query(db, "q1")
     got = _run_query(db, "q1", dict(device_row_budget=64,
                                     stream_double_buffer=False))
-    _assert_biteq("q1/sync", ref, got)
+    assert_biteq("q1/sync", ref, got)
 
 
 def test_streamed_exact_cf_frequency_slabs():
@@ -165,7 +152,7 @@ def test_streamed_exact_cf_frequency_slabs():
                   plan_opts=dict(cf_budget_elems=256))
     got = tpch.q6(db, "aggregate", num_freq=256,
                   plan_opts=dict(cf_budget_elems=256, device_row_budget=64))
-    _assert_biteq("q6/cf_slabs", ref, got)
+    assert_biteq("q6/cf_slabs", ref, got)
 
 
 # ----------------------------------------------------- host-table surface
@@ -179,8 +166,8 @@ def test_host_table_streams_and_materialises():
     host = dict(dev)
     host["lineitem"] = HostTable.from_table(db.lineitem)
     ref = compile_plan(plan, None)(dev)
-    _assert_biteq("host/resident", ref, compile_plan(plan, None)(host))
-    _assert_biteq("host/streamed", ref,
+    assert_biteq("host/resident", ref, compile_plan(plan, None)(host))
+    assert_biteq("host/streamed", ref,
                   compile_plan(plan, None, device_row_budget=64)(host))
 
 
@@ -248,7 +235,7 @@ def test_single_row_and_one_chunk_tables():
     got = compile_plan(plan, None, canonical_chunks=1,
                        device_row_budget=4)(
         {"t": HostTable.from_table(one)})
-    _assert_biteq("one-chunk", ref, got)
+    assert_biteq("one-chunk", ref, got)
 
 
 def test_select_columns_shares_arrays_and_pad():
@@ -294,7 +281,7 @@ def test_stats_tables_accepts_host_table():
     fn = compile_plan(plan, stats_tables=stats,
                       join_gather_budget=1)   # force exchanges
     got = jax.jit(fn)(tabs)
-    _assert_biteq("stats/host", ref, got)
+    assert_biteq("stats/host", ref, got)
 
 
 def test_pad_to_multiple_cached():
@@ -336,56 +323,34 @@ def test_streamed_requires_aggregation():
 
 
 # ------------------------------------------------------------ mesh waves
-@pytest.mark.multidevice
-@pytest.mark.parametrize("devices", [2, 3])
-def test_streamed_mesh_bit_equal(devices):
-    """Streamed execution on a real multi-device mesh — including the
-    3-shard count that does not divide the 8-chunk grid — is bit-equal to
-    the single-device RESIDENT compile, across query shapes (plain agg,
-    join spine, scalar agg, reweight, plan suffix above the streamed
-    pass) and a 1-chunk wave schedule."""
-    from conftest import run_sub
-    out = run_sub("""
-import jax, numpy as np
-from repro.core import enable_x64
+_STREAMED_MESH = """
 enable_x64()
-from repro.db import tpch
-
-mesh = jax.make_mesh((%(devices)d,), ("data",),
-                     axis_types=(jax.sharding.AxisType.Auto,))
 db = tpch.generate(n_orders=48, lines_per_order=4, n_parts=24,
                    n_suppliers=8, n_customers=24, seed=0)
 
-def biteq(name, ref, got):
-    la, ta = jax.tree.flatten(ref)
-    lb, tb = jax.tree.flatten(got)
-    assert str(ta) == str(tb), name
-    for a, b in zip(la, lb):
-        a, b = np.asarray(a), np.asarray(b)
-        assert a.shape == b.shape and a.dtype == b.dtype, name
-        f = a.astype(np.float64, copy=False)
-        g = b.astype(np.float64, copy=False)
-        assert ((a == b) | (np.isnan(f) & np.isnan(g))).all(), name
-
 opts = dict(device_row_budget=128)
-biteq("q1", tpch.q1(db, "aggregate"),
-      tpch.q1(db, "aggregate", mesh=mesh, plan_opts=opts))
-biteq("q3", tpch.q3(db, "aggregate", max_groups=64),
-      tpch.q3(db, "aggregate", max_groups=64, mesh=mesh, plan_opts=opts))
-biteq("q6", tpch.q6(db, "aggregate", num_freq=256),
-      tpch.q6(db, "aggregate", num_freq=256, mesh=mesh, plan_opts=opts))
-biteq("q18", tpch.q18(db, "aggregate", max_groups=64),
-      tpch.q18(db, "aggregate", max_groups=64, mesh=mesh, plan_opts=opts))
-biteq("q20", tpch.q20(db, "aggregate", max_groups=64),
-      tpch.q20(db, "aggregate", max_groups=64, mesh=mesh, plan_opts=opts))
-biteq("q1_wc1", tpch.q1(db, "aggregate"),
-      tpch.q1(db, "aggregate", mesh=mesh,
-              plan_opts=dict(device_row_budget=128, stream_wave_chunks=1)))
+assert_biteq("q1", tpch.q1(db, "aggregate"),
+             tpch.q1(db, "aggregate", mesh=mesh, plan_opts=opts))
+assert_biteq("q3", tpch.q3(db, "aggregate", max_groups=64),
+             tpch.q3(db, "aggregate", max_groups=64, mesh=mesh,
+                     plan_opts=opts))
+assert_biteq("q6", tpch.q6(db, "aggregate", num_freq=256),
+             tpch.q6(db, "aggregate", num_freq=256, mesh=mesh,
+                     plan_opts=opts))
+assert_biteq("q18", tpch.q18(db, "aggregate", max_groups=64),
+             tpch.q18(db, "aggregate", max_groups=64, mesh=mesh,
+                      plan_opts=opts))
+assert_biteq("q20", tpch.q20(db, "aggregate", max_groups=64),
+             tpch.q20(db, "aggregate", max_groups=64, mesh=mesh,
+                      plan_opts=opts))
+assert_biteq("q1_wc1", tpch.q1(db, "aggregate"),
+             tpch.q1(db, "aggregate", mesh=mesh,
+                     plan_opts=dict(device_row_budget=128,
+                                    stream_wave_chunks=1)))
 
 # disk-backed (save -> open, mmap columns) lineitem on the mesh, with
 # and without column pruning
 import tempfile
-from repro.db.plans import compile_plan
 from repro.db.table import HostTable
 tabs = db.tables()
 ref = compile_plan(tpch.q1_plan(), mesh)(tabs)
@@ -396,7 +361,21 @@ with tempfile.TemporaryDirectory() as d:
     for prune in (True, False):
         got = compile_plan(tpch.q1_plan(), mesh, device_row_budget=128,
                            stream_prune_columns=prune)(disk)
-        biteq("q1_disk_prune=%%s" %% prune, ref, got)
+        assert_biteq("q1_disk_prune=%s" % prune, ref, got)
 print("STREAM BITEQ OK")
-""" % dict(devices=devices), devices=devices)
-    assert "STREAM BITEQ OK" in out
+"""
+
+MESH_CHECKS = {f"test_streamed_mesh_bit_equal[{d}]":
+               MeshCheck(d, _STREAMED_MESH, seconds=seconds)
+               for d, seconds in ((2, 70), (3, 80))}
+
+
+@pytest.mark.multidevice
+@pytest.mark.parametrize("devices", [2, 3])
+def test_streamed_mesh_bit_equal(mesh_check, devices):
+    """Streamed execution on a real multi-device mesh — including the
+    3-shard count that does not divide the 8-chunk grid — is bit-equal to
+    the single-device RESIDENT compile, across query shapes (plain agg,
+    join spine, scalar agg, reweight, plan suffix above the streamed
+    pass) and a 1-chunk wave schedule."""
+    assert "STREAM BITEQ OK" in mesh_check()

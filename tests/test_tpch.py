@@ -4,6 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import MeshCheck, equiv_check
 from repro.db import operators as ops
 from repro.db import tpch
 
@@ -91,63 +92,33 @@ def test_queries_scale_invariant_shapes():
 
 
 # --------------------------------------------------- sharded frontend
-@pytest.mark.multidevice
-@pytest.mark.parametrize("qname", sorted(tpch.QUERIES))
-def test_query_mesh_bit_equal(mesh_equiv, qname):
-    """Each TPC-H plan through the sharded frontend on a 2-device mesh is
-    BIT-IDENTICAL to the single-device compile, in every probabilistic
-    mode (scan/join/group-id inputs sharded end-to-end)."""
-    mesh_equiv(f"""
-db = tpch.generate(n_orders=48, seed=3)
+# The multi-device checks, one subprocess per device count (conftest
+# `mesh_check`).  Every script draws the same database from `memo`, and
+# the aggregate-mode reference of each query is computed once per process.
+_DB = 'db = memo("db", lambda: tpch.generate(n_orders=48, seed=3))\n'
+
+
+def _query_equiv(qname, seconds):
+    return equiv_check(_DB + f"""
 fn = tpch.QUERIES[{qname!r}]
-pairs = [(mode, fn(db, mode), fn(db, mode, mesh=mesh))
+pairs = [(mode, memo((mode, {qname!r}), lambda: fn(db, mode)),
+          fn(db, mode, mesh=mesh))
          for mode in ("confidence", "group_confidence", "aggregate")]
-""")
+""", seconds=seconds)
 
 
-@pytest.mark.multidevice
-@pytest.mark.parametrize("devices", [2, 3, 4])
-def test_all_queries_mesh_bit_equal_any_shard_count(mesh_equiv, devices):
-    """The determinism contract on 2-, 3- and 4-shard meshes for ALL five
-    TPC-H queries (aggregate mode), both with the default gather-join
-    lowering and with a tiny join_gather_budget that lowers every
-    over-budget FK join to a hash-exchange strategy — pinned to the
-    unfused ShuffleJoin + shuffle-home + PartialAgg path with
-    copartition=False (the cost model would otherwise fuse q3's GROUP
-    BY; the fused pipeline has its own dedicated parity test) — one
-    subprocess per shard count."""
-    mesh_equiv("""
-db = tpch.generate(n_orders=48, seed=3)
+_ALL_QUERIES = _DB + """
 shuffle = dict(join_gather_budget=4, copartition=False)
 pairs = []
 for qname, fn in sorted(tpch.QUERIES.items()):
-    ref = fn(db, "aggregate")
+    ref = memo(("aggregate", qname), lambda: fn(db, "aggregate"))
     pairs.append((qname, ref, fn(db, "aggregate", mesh=mesh)))
     pairs.append((qname + "/shuffle", ref,
                   fn(db, "aggregate", mesh=mesh, plan_opts=shuffle)))
-""", devices=devices)
+"""
 
-
-@pytest.mark.multidevice
-@pytest.mark.parametrize("devices", [2, 3, 4])
-def test_q3_q18_copartitioned_bit_equal_zero_roundtrips(devices):
-    """The fused shuffle -> aggregate pipeline on real queries: Q3 with a
-    per-join budget that hash-exchanges the orders join (the GROUP BY
-    keys on l_orderkey, so the cost model fuses it) and Q18 with
-    ``agg_shuffle_budget`` repartitioning the lineitem aggregation — both
-    BIT-IDENTICAL to the single-device compile on 2-, 3- and 4-shard
-    meshes, with ZERO shuffle_back round-trips (asserted via the
-    collective counter) and the one-psum partitioned merge."""
-    from conftest import run_sub
-    run_sub("""
-import jax, numpy as np
-from repro.core import enable_x64
-enable_x64()
+_COPARTITIONED = "enable_x64()\n" + _DB + """
 from repro.db import distributed as dist
-from repro.db import physical as phys, tpch
-mesh = jax.make_mesh((__D__,), ("data",),
-                     axis_types=(jax.sharding.AxisType.Auto,))
-db = tpch.generate(n_orders=48, seed=3)
 for qname, kwargs, opts in (("q3", dict(order_join_budget=4), {}),
                             ("q18", {}, dict(agg_shuffle_budget=4))):
     fn = tpch.QUERIES[qname]
@@ -158,11 +129,57 @@ for qname, kwargs, opts in (("q3", dict(order_join_budget=4), {}),
         c = dict(dist.COLLECTIVE_COUNTS)
         assert c.get("shuffle_back", 0) == 0, (qname, mode, c)
         assert c.get("merge_psum", 0) >= 1, (qname, mode, c)
-        for a, b in zip(jax.tree.leaves(ref), jax.tree.leaves(got)):
-            assert np.array_equal(np.asarray(a), np.asarray(b)), \\
-                (qname, mode)
+        assert_biteq(qname + "/" + mode, ref, got, nan_equal=False)
 print("FUSED OK")
-""".replace("__D__", str(devices)), devices=devices)
+"""
+
+# `seconds`: measured on the CPU with four batches running at once.  With
+# two devices the all-queries check finds its references in `memo`.
+MESH_CHECKS = {
+    **{f"test_query_mesh_bit_equal[{q}]": _query_equiv(q, seconds)
+       for q, seconds in (("q1", 20), ("q18", 15), ("q20", 40), ("q3", 20),
+                          ("q6", 5))},
+    **{f"test_all_queries_mesh_bit_equal_any_shard_count[{d}]":
+       equiv_check(_ALL_QUERIES, devices=d, seconds=seconds)
+       for d, seconds in ((2, 60), (3, 145), (4, 130))},
+    **{f"test_q3_q18_copartitioned_bit_equal_zero_roundtrips[{d}]":
+       MeshCheck(d, _COPARTITIONED, seconds=15) for d in (2, 3, 4)},
+}
+
+
+@pytest.mark.multidevice
+@pytest.mark.parametrize("qname", sorted(tpch.QUERIES))
+def test_query_mesh_bit_equal(mesh_check, qname):
+    """Each TPC-H plan through the sharded frontend on a 2-device mesh is
+    BIT-IDENTICAL to the single-device compile, in every probabilistic
+    mode (scan/join/group-id inputs sharded end-to-end)."""
+    assert "BITEQ OK" in mesh_check()
+
+
+@pytest.mark.multidevice
+@pytest.mark.parametrize("devices", [2, 3, 4])
+def test_all_queries_mesh_bit_equal_any_shard_count(mesh_check, devices):
+    """The determinism contract on 2-, 3- and 4-shard meshes for ALL five
+    TPC-H queries (aggregate mode), both with the default gather-join
+    lowering and with a tiny join_gather_budget that lowers every
+    over-budget FK join to a hash-exchange strategy — pinned to the
+    unfused ShuffleJoin + shuffle-home + PartialAgg path with
+    copartition=False (the cost model would otherwise fuse q3's GROUP
+    BY; the fused pipeline has its own dedicated parity test)."""
+    assert "BITEQ OK" in mesh_check()
+
+
+@pytest.mark.multidevice
+@pytest.mark.parametrize("devices", [2, 3, 4])
+def test_q3_q18_copartitioned_bit_equal_zero_roundtrips(mesh_check, devices):
+    """The fused shuffle -> aggregate pipeline on real queries: Q3 with a
+    per-join budget that hash-exchanges the orders join (the GROUP BY
+    keys on l_orderkey, so the cost model fuses it) and Q18 with
+    ``agg_shuffle_budget`` repartitioning the lineitem aggregation — both
+    BIT-IDENTICAL to the single-device compile on 2-, 3- and 4-shard
+    meshes, with ZERO shuffle_back round-trips (asserted via the
+    collective counter) and the one-psum partitioned merge."""
+    assert "FUSED OK" in mesh_check()
 
 
 def test_deterministic_db_gives_deterministic_answers():

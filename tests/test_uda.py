@@ -9,6 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import MeshCheck, equiv_check
 from repro.core import uda
 from repro.core.config import default_float
 from repro.core.pgf import possible_worlds_pgf
@@ -205,12 +206,7 @@ def test_every_registered_uda_constructs():
 
 
 # --------------------------------------------------- mesh-aware compilation
-@pytest.mark.multidevice
-def test_compile_plan_mesh_equivalence(mesh_equiv):
-    """compile_plan(root, mesh) is BIT-EQUAL to compile_plan(root) on a
-    2-device CPU mesh, across GroupAgg methods, MIN/MAX, and
-    ReweightGreater (the sharded frontend's canonical-chunk fold tree)."""
-    mesh_equiv("""
+_MESH_EQUIVALENCE = """
 db = tpch.generate(n_orders=64, seed=5)
 tables = db.tables()
 plans = {
@@ -229,16 +225,50 @@ plans = {
 }
 pairs = [(name, compile_plan(p, None)(tables), compile_plan(p, mesh)(tables))
          for name, p in plans.items()]
-""")
+"""
 
 
 @pytest.mark.multidevice
-def test_compile_plan_4dev_and_jit_bit_equal(mesh_equiv):
-    """The determinism contract holds for any power-of-two shard count
-    dividing the canonical chunk grid (here 4), and under jit (comparing
-    jitted against jitted — XLA fusion differs between jit and eager, but
-    sharding never does)."""
-    mesh_equiv("""
+def test_compile_plan_mesh_equivalence(mesh_check):
+    """compile_plan(root, mesh) is BIT-EQUAL to compile_plan(root) on a
+    2-device CPU mesh, across GroupAgg methods, MIN/MAX, and
+    ReweightGreater (the sharded frontend's canonical-chunk fold tree)."""
+    assert "BITEQ OK" in mesh_check()
+
+
+_SECOND_RUN_COMPILES_NOTHING = """
+enable_x64()
+compiles = []
+jax.monitoring.register_event_duration_secs_listener(
+    lambda event, secs, **kw: compiles.append(event)
+    if event == "/jax/core/compile/backend_compile_duration" else None)
+tables = tpch.generate(n_orders=64, seed=5).tables()
+plan = lambda: GroupAgg(Scan("lineitem"), ("l_returnflag",), "l_quantity",
+                        "SUM", 24, "normal",
+                        extra=(("c", "l_quantity", "SUM", "cumulants"),))
+for m in (mesh, None):
+    fn = compile_plan(plan(), m)
+    first = fn(tables)
+    n = len(compiles)
+    assert n > 0, m
+    assert_biteq("again", first, fn(tables), nan_equal=False)
+    assert_biteq("recompiled", first, compile_plan(plan(), m)(tables),
+                 nan_equal=False)
+    assert len(compiles) == n, (m, len(compiles) - n)
+print("NO RECOMPILES OK")
+"""
+
+
+@pytest.mark.multidevice
+def test_second_eager_run_compiles_nothing(mesh_check):
+    """An eager run on a 2-device mesh (and on one device) compiles each
+    of its programs once: running the plan again — by the same compiled
+    function or by a fresh compile of an equal plan — compiles nothing,
+    and answers bit for bit the same."""
+    assert "NO RECOMPILES OK" in mesh_check()
+
+
+_4DEV_AND_JIT = """
 db = tpch.generate(n_orders=64, seed=5)
 tables = db.tables()
 plan = GroupAgg(Scan("lineitem"), ("l_orderkey",), "l_quantity", "SUM", 128,
@@ -249,7 +279,16 @@ pairs = [
     ("jit", jax.jit(compile_plan(plan, None))(tables),
      jax.jit(compile_plan(plan, mesh))(tables)),
 ]
-""", devices=4)
+"""
+
+
+@pytest.mark.multidevice
+def test_compile_plan_4dev_and_jit_bit_equal(mesh_check):
+    """The determinism contract holds for any power-of-two shard count
+    dividing the canonical chunk grid (here 4), and under jit (comparing
+    jitted against jitted — XLA fusion differs between jit and eager, but
+    sharding never does)."""
+    assert "BITEQ OK" in mesh_check()
 
 
 # ------------------------------------------- generalized canonical fold
@@ -298,15 +337,7 @@ def test_accumulate_chunk_states_fold_matches_chunked(num_chunks):
                                np.asarray(flat["n"].terms), rtol=1e-12)
 
 
-@pytest.mark.multidevice
-def test_sumcf_model_window_reduce_model_bit_equal():
-    """The model-axis frequency-split primitive, isolated: each model rank
-    accumulates its SumCF window (traced freq_lo = rank * f_loc) over the
-    SAME replicated tuples, ``reduce_model`` reassembles with one tiled
-    all-gather — BIT-equal to the full-width accumulate (per-frequency CF
-    columns are computed independently, so the split changes nothing)."""
-    from conftest import run_sub
-    out = run_sub("""
+_SUMCF_MODEL_WINDOW = """
 import jax, jax.numpy as jnp, numpy as np
 from functools import partial
 from jax.sharding import PartitionSpec as P
@@ -336,17 +367,20 @@ for a, b in zip(want, got):
     assert a.shape == b.shape, (a.shape, b.shape)
     assert jnp.array_equal(a, b), float(jnp.max(jnp.abs(a - b)))
 print("BITEQ OK")
-""")
-    assert "BITEQ OK" in out
+"""
 
 
 @pytest.mark.multidevice
-def test_compile_plan_3dev_non_pow2_bit_equal(mesh_equiv):
-    """The determinism contract now covers shard counts that do NOT
-    divide the canonical chunk grid: every chunk state is computed on one
-    shard, gathered, and folded in the one fixed tree — 3 devices against
-    the 8-chunk grid, eager and jit, plus a non-pow2 grid."""
-    mesh_equiv("""
+def test_sumcf_model_window_reduce_model_bit_equal(mesh_check):
+    """The model-axis frequency-split primitive, isolated: each model rank
+    accumulates its SumCF window (traced freq_lo = rank * f_loc) over the
+    SAME replicated tuples, ``reduce_model`` reassembles with one tiled
+    all-gather — BIT-equal to the full-width accumulate (per-frequency CF
+    columns are computed independently, so the split changes nothing)."""
+    assert "BITEQ OK" in mesh_check()
+
+
+_3DEV_NON_POW2 = """
 db = tpch.generate(n_orders=64, seed=5)
 tables = db.tables()
 plan = GroupAgg(Scan("lineitem"), ("l_orderkey",), "l_quantity", "SUM", 128,
@@ -358,4 +392,29 @@ pairs = [
      jax.jit(compile_plan(plan, mesh))(tables)),
     ("chunks6", mk(canonical_chunks=6), mk(mesh, canonical_chunks=6)),
 ]
-""", devices=3)
+"""
+
+
+@pytest.mark.multidevice
+def test_compile_plan_3dev_non_pow2_bit_equal(mesh_check):
+    """The determinism contract now covers shard counts that do NOT
+    divide the canonical chunk grid: every chunk state is computed on one
+    shard, gathered, and folded in the one fixed tree — 3 devices against
+    the 8-chunk grid, eager and jit, plus a non-pow2 grid."""
+    assert "BITEQ OK" in mesh_check()
+
+
+# ------------------------------------------------ the multi-device checks
+# (conftest `mesh_check`: one subprocess per device count)
+MESH_CHECKS = {
+    "test_compile_plan_mesh_equivalence": equiv_check(_MESH_EQUIVALENCE,
+                                                      seconds=60),
+    "test_second_eager_run_compiles_nothing": MeshCheck(
+        2, _SECOND_RUN_COMPILES_NOTHING, seconds=5),
+    "test_sumcf_model_window_reduce_model_bit_equal": MeshCheck(
+        2, _SUMCF_MODEL_WINDOW, seconds=10),
+    "test_compile_plan_4dev_and_jit_bit_equal": equiv_check(
+        _4DEV_AND_JIT, devices=4, seconds=15),
+    "test_compile_plan_3dev_non_pow2_bit_equal": equiv_check(
+        _3DEV_NON_POW2, devices=3, seconds=35),
+}
